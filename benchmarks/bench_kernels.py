@@ -16,7 +16,7 @@ import numpy as np
 
 from repro.core import build_store, route, shared_attention_batched, \
     shared_attention_gather_ref
-from repro.launch.mesh import HW
+from repro.launch.roofline import V5E, peaks
 
 
 def _time(f, *args, n=5):
@@ -57,5 +57,6 @@ def run(emit):
              f"intensity={flops/bytes_gemm:.1f}flops_per_byte")
         emit(f"kernels/shared_attn/N{N}/gather_gemv_us", t_g,
              f"intensity={flops/bytes_gemv:.1f}flops_per_byte")
-    ridge = HW["peak_flops_bf16"] / HW["hbm_bw"]
+    v5e = peaks(V5E)
+    ridge = v5e["peak_flops_bf16"] / v5e["hbm_bw"]
     emit("kernels/v5e_ridge_point_flops_per_byte", 0.0, f"{ridge:.0f}")

@@ -49,7 +49,7 @@ def run(emit):
     # true attention mass per chunk (layer 0)
     KH, D = cfg.num_kv_heads, cfg.head_dim
     H = cfg.num_heads
-    kf = store.k[0].reshape(E * C, KH, D)
+    kf = store.k[0].transpose(0, 2, 1, 3).reshape(E * C, KH, D)
     qg = q.reshape(B, KH, H // KH, D)
     s = jnp.einsum("bkgd,skd->bkgs", qg, kf) / math.sqrt(D)
     p = jax.nn.softmax(s, axis=-1)

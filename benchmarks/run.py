@@ -11,8 +11,11 @@ Prints ``name,us_per_call,derived`` CSV rows.
 """
 import sys
 
+from repro.launch.compile_cache import init_compile_cache
+
 
 def main() -> None:
+    init_compile_cache()
     mods = ["bench_fig1", "bench_fig4", "bench_fig5", "bench_kernels",
             "bench_router", "bench_serving", "bench_roofline"]
     if len(sys.argv) > 1:

@@ -28,7 +28,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import MoSKAConfig
@@ -40,7 +39,7 @@ NEG_INF = -1e30
 
 def disaggregated_shared_attention(
     q: jax.Array,              # (B, H, D) decode queries, batch-sharded
-    store_k: jax.Array,        # (E, C, KH, D) chunk-sharded over axis
+    store_k: jax.Array,        # (E, KH, C, D) chunk-sharded over axis
     store_v: jax.Array,
     emb: jax.Array,            # (E, KH, D) chunk-sharded
     cfg: MoSKAConfig,
@@ -86,9 +85,14 @@ def disaggregated_shared_attention(
         return out.astype(q_l.dtype), lse
 
     cspec = P(chunk_axis)
-    return shard_map(
+    # The pmax/psum combine makes both outputs identical on every chunk
+    # owner. Varying-axes checking stays off: a Pallas kernel
+    # (kernel="pallas") cannot be traced under it, neither compiled (its
+    # out_shape carries no vma) nor interpreted (JAX 0.9 rejects the
+    # interpreter's slices of varying operands).
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(batch_axis), cspec, cspec, cspec),
         out_specs=(P(batch_axis), P(batch_axis)),
-        check_rep=False,
+        check_vma=False,
     )(q, store_k, store_v, emb)

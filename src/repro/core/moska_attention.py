@@ -35,8 +35,8 @@ def _record_merge(lse_u: jax.Array, lse_s: jax.Array, phase: str) -> None:
 
 class MoskaLayerContext(NamedTuple):
     """Per-layer shared store slices + routing, computed once per step."""
-    k: jax.Array                         # (E, C, KH, D)
-    v: jax.Array                         # (E, C, KH, D)
+    k: jax.Array                         # (E, KH, C, D)
+    v: jax.Array                         # (E, KH, C, D)
     routing: router_lib.Routing
 
 

@@ -76,28 +76,30 @@ class SharedPartial(NamedTuple):
 
 def _chunk_batched_attention(qd: jax.Array, k: jax.Array, v: jax.Array,
                              qmask: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """qd: (E, cap, Q, H, D) dispatched queries; k/v: (E, C, KH, D);
+    """qd: (E, cap, Q, H, D) dispatched queries; k/v: (E, KH, C, D);
     qmask: (E, cap) validity. Non-causal (corpus precedes all queries).
 
     Returns out (E, cap, Q, H, D), lse (E, cap, Q, H) fp32.
     """
     E, cap, Q, H, D = qd.shape
-    KH = k.shape[2]
+    KH = k.shape[1]
     G = H // KH
     scale = 1.0 / math.sqrt(D)
     qg = qd.reshape(E, cap, Q, KH, G, D)
-    s = jnp.einsum("ecqkgd,eskd->ecqkgs", qg, k,
+    # kv-head-major (E, KH, cap, Q, G, ...) intermediates, like the store
+    s = jnp.einsum("ecqkgd,eksd->ekcqgs", qg, k,
                    preferred_element_type=jnp.float32) * scale
     m = jnp.max(s, axis=-1)
     p = jnp.exp(s - m[..., None])
     l = jnp.sum(p, axis=-1)
-    o = jnp.einsum("ecqkgs,eskd->ecqkgd", p.astype(v.dtype), v,
+    o = jnp.einsum("ekcqgs,eksd->ekcqgd", p.astype(v.dtype), v,
                    preferred_element_type=jnp.float32)
     o = o / jnp.maximum(l, 1e-37)[..., None]
     lse = m + jnp.log(jnp.maximum(l, 1e-37))
-    lse = jnp.where(qmask[:, :, None, None, None], lse, NEG_INF)
-    out = o.reshape(E, cap, Q, H, D).astype(qd.dtype)
-    return out, lse.reshape(E, cap, Q, H)
+    out = o.transpose(0, 2, 3, 1, 4, 5).reshape(E, cap, Q, H, D)
+    lse = lse.transpose(0, 2, 3, 1, 4).reshape(E, cap, Q, H)
+    lse = jnp.where(qmask[:, :, None, None], lse, NEG_INF)
+    return out.astype(qd.dtype), lse
 
 
 # ---------------------------------------------------------------------------
@@ -106,8 +108,8 @@ def _chunk_batched_attention(qd: jax.Array, k: jax.Array, v: jax.Array,
 
 def shared_attention_batched(
     q: jax.Array,                  # (G, Q, H, D) query groups (Q=1 decode)
-    layer_store_k: jax.Array,      # (E, C, KH, D)
-    layer_store_v: jax.Array,      # (E, C, KH, D)
+    layer_store_k: jax.Array,      # (E, KH, C, D)
+    layer_store_v: jax.Array,      # (E, KH, C, D)
     routing: router_lib.Routing,
     *,
     capacity: Optional[int] = None,
@@ -118,7 +120,7 @@ def shared_attention_batched(
 ) -> SharedPartial:
     """Batched Shared KV Attention over routed chunks."""
     G, Q, H, D = q.shape
-    E, C, KH, _ = layer_store_k.shape
+    E = layer_store_k.shape[0]
     K = routing.chunk_ids.shape[1]
     if capacity is None:
         capacity = router_lib.required_capacity(G, K, E, capacity_factor)
@@ -136,14 +138,13 @@ def shared_attention_batched(
     _record_dispatch(qmask, keep, layer_idx)
 
     if kernel == "pallas":
-        from repro.kernels import ops as kops
+        from repro.kernels.shared_chunk_attn import shared_chunk_attention
         # kernel takes (E, cap, H, D): fold the per-group query dim into cap
         qd_k = qd.reshape(E, capacity * Q, H, D)
         qm_k = jnp.repeat(qmask, Q, axis=1)
         kern_kwargs = {} if block_c is None else {"block_c": block_c}
-        od, lsed = kops.shared_chunk_attention(qd_k, layer_store_k,
-                                               layer_store_v, qm_k,
-                                               **kern_kwargs)
+        od, lsed = shared_chunk_attention(qd_k, layer_store_k,
+                                          layer_store_v, qm_k, **kern_kwargs)
         od = od.reshape(E, capacity, Q, H, D)
         lsed = lsed.reshape(E, capacity, Q, H)
     else:
@@ -179,7 +180,7 @@ def shared_attention_batched(
 
 def shared_attention_gather_ref(
     q: jax.Array,                  # (G, Q, H, D)
-    layer_store_k: jax.Array,      # (E, C, KH, D)
+    layer_store_k: jax.Array,      # (E, KH, C, D)
     layer_store_v: jax.Array,
     routing: router_lib.Routing,
 ) -> SharedPartial:
@@ -188,20 +189,20 @@ def shared_attention_gather_ref(
     re-reads its chunks) — this is the baseline MoSKA's GEMM batching beats.
     """
     G, Q, H, D = q.shape
-    E, C, KH, _ = layer_store_k.shape
+    E, KH, C, _ = layer_store_k.shape
     K = routing.chunk_ids.shape[1]
     scale = 1.0 / math.sqrt(D)
-    ksel = layer_store_k[routing.chunk_ids]                  # (G, K, C, KH, D)
+    ksel = layer_store_k[routing.chunk_ids]                  # (G, K, KH, C, D)
     vsel = layer_store_v[routing.chunk_ids]
-    ksel = ksel.reshape(G, K * C, KH, D)
-    vsel = vsel.reshape(G, K * C, KH, D)
+    ksel = ksel.transpose(0, 2, 1, 3, 4).reshape(G, KH, K * C, D)
+    vsel = vsel.transpose(0, 2, 1, 3, 4).reshape(G, KH, K * C, D)
     qg = q.reshape(G, Q, KH, H // KH, D)
-    s = jnp.einsum("gqkhd,gskd->gqkhs", qg, ksel,
+    s = jnp.einsum("gqkhd,gksd->gqkhs", qg, ksel,
                    preferred_element_type=jnp.float32) * scale
     m = jnp.max(s, axis=-1)
     p = jnp.exp(s - m[..., None])
     l = jnp.sum(p, axis=-1)
-    o = jnp.einsum("gqkhs,gskd->gqkhd", p.astype(vsel.dtype), vsel,
+    o = jnp.einsum("gqkhs,gksd->gqkhd", p.astype(vsel.dtype), vsel,
                    preferred_element_type=jnp.float32)
     o = o / jnp.maximum(l, 1e-37)[..., None]
     lse = (m + jnp.log(jnp.maximum(l, 1e-37))).reshape(G, Q, H)

@@ -7,8 +7,12 @@ over the ``data`` (and ``pod``) mesh axes at serve time (DESIGN.md §5).
 
 Layout (stacked over layers so the decoder `lax.scan` consumes one slice
 per layer):
-    k, v : (L, n_chunks, chunk_size, kv_heads, head_dim)   post-RoPE keys
+    k, v : (L, n_chunks, kv_heads, chunk_size, head_dim)   post-RoPE keys
     emb  : (L, n_chunks, kv_heads, head_dim)               router embeddings
+
+Keys and values are laid out per kv head, so one kv head's tile of one
+chunk is a contiguous (chunk tile, head_dim) block — the block shape the
+TPU kernel ``kernels.shared_chunk_attn`` needs.
 """
 from __future__ import annotations
 
@@ -22,16 +26,16 @@ from repro.configs.base import ModelConfig
 
 
 class SharedKVStore(NamedTuple):
-    k: jax.Array            # (L, E, C, KH, D)  bf16, or int8 when quantized
-    v: jax.Array            # (L, E, C, KH, D)
+    k: jax.Array            # (L, E, KH, C, D)  bf16, or int8 when quantized
+    v: jax.Array            # (L, E, KH, C, D)
     emb: jax.Array          # (L, E, KH, D) mean-key chunk embeddings
     # absolute corpus position of the first token of each chunk; chunk i is
     # contiguous. positional=False => chunk-local positions (Universal MoSKA)
     chunk_positions: jax.Array  # (E,) int32
     # int8 quantization scales (None => unquantized). Per (layer, chunk,
-    # token, kv_head): the TPU analogue of the paper's FP8 KV (v5e has no
+    # kv_head, token): the TPU analogue of the paper's FP8 KV (v5e has no
     # FP8; int8 gives the same capacity/bandwidth halving).
-    k_scale: Optional[jax.Array] = None   # (L, E, C, KH) f32
+    k_scale: Optional[jax.Array] = None   # (L, E, KH, C) f32
     v_scale: Optional[jax.Array] = None
 
     @property
@@ -58,7 +62,7 @@ class SharedKVStore(NamedTuple):
 
     @property
     def chunk_size(self) -> int:
-        return self.k.shape[2]
+        return self.k.shape[3]
 
     @property
     def total_tokens(self) -> int:
@@ -72,9 +76,9 @@ class SharedKVStore(NamedTuple):
 def chunk_embeddings(k_chunks: jax.Array) -> jax.Array:
     """Training-free router embeddings: mean key per chunk (LongHeads/MoBA).
 
-    k_chunks: (..., E, C, KH, D) -> (..., E, KH, D)
+    k_chunks: (..., E, KH, C, D) -> (..., E, KH, D)
     """
-    return jnp.mean(k_chunks.astype(jnp.float32), axis=-3).astype(
+    return jnp.mean(k_chunks.astype(jnp.float32), axis=-2).astype(
         k_chunks.dtype)
 
 
@@ -102,8 +106,8 @@ def build_store(k: jax.Array, v: jax.Array, chunk_size: int,
         raise ValueError(f"corpus length {S} not a multiple of chunk_size "
                          f"{chunk_size}")
     E = S // chunk_size
-    kc = k.reshape(L, E, chunk_size, KH, D)
-    vc = v.reshape(L, E, chunk_size, KH, D)
+    kc = k.reshape(L, E, chunk_size, KH, D).transpose(0, 1, 3, 2, 4)
+    vc = v.reshape(L, E, chunk_size, KH, D).transpose(0, 1, 3, 2, 4)
     emb = chunk_embeddings(kc)
     pos = start_position + jnp.arange(E, dtype=jnp.int32) * chunk_size
     if not quantize:
@@ -123,10 +127,10 @@ def abstract_store(cfg: ModelConfig, shared_tokens: int,
     sds = jax.ShapeDtypeStruct
     quant = cfg.moska.kv_quant == "int8"
     return SharedKVStore(
-        k=sds((L, E, C, KH, D), jnp.int8 if quant else dtype),
-        v=sds((L, E, C, KH, D), jnp.int8 if quant else dtype),
+        k=sds((L, E, KH, C, D), jnp.int8 if quant else dtype),
+        v=sds((L, E, KH, C, D), jnp.int8 if quant else dtype),
         emb=sds((L, E, KH, D), dtype),
         chunk_positions=sds((E,), jnp.int32),
-        k_scale=sds((L, E, C, KH), jnp.float32) if quant else None,
-        v_scale=sds((L, E, C, KH), jnp.float32) if quant else None,
+        k_scale=sds((L, E, KH, C), jnp.float32) if quant else None,
+        v_scale=sds((L, E, KH, C), jnp.float32) if quant else None,
     )

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -67,7 +67,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      kv_len: jax.Array, *, block_s: int = 1024,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """q: (B, H, D); k/v: (B, S, KH, D); kv_len: (B,) valid lengths.
 
     Returns (out (B, H, D), lse (B, H) fp32).
@@ -105,9 +105,9 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, D), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
         name="moska_unique_decode_attn",
     )(lens, qg, k, v)
 

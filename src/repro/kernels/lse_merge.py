@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -39,7 +39,7 @@ def _kernel(o_ref, l_ref, out_ref, lse_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def lse_merge(outs: jax.Array, lses: jax.Array, *, block_n: int = 256,
-              interpret: bool = True):
+              interpret: bool | None = None):
     """outs: (P, N, H, D); lses: (P, N, H) -> (out (N,H,D), lse (N,H))."""
     P, N, H, D = outs.shape
     block_n = min(block_n, N)
@@ -60,9 +60,9 @@ def lse_merge(outs: jax.Array, lses: jax.Array, *, block_n: int = 256,
             jax.ShapeDtypeStruct((N, H, D), outs.dtype),
             jax.ShapeDtypeStruct((N, H), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
         name="moska_lse_merge",
     )(outs, lses)
     return out, lse

@@ -30,7 +30,7 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 from repro.kernels import ref
-from repro.kernels.compat import CompilerParams
+from repro.kernels import resolve_interpret
 from repro.kvcache.paged import gather_layer
 
 NEG_INF = -1e30
@@ -95,7 +95,8 @@ def _kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array, table: jax.Array,
-                           kv_len: jax.Array, *, interpret: bool = True
+                           kv_len: jax.Array, *,
+                           interpret: bool | None = None
                            ) -> Tuple[jax.Array, jax.Array]:
     """q: (B, H, D); k_pool/v_pool: (N, bs, KH, D) physical page pools;
     table: (B, M) int32 block tables (NULL-padded); kv_len: (B,).
@@ -148,9 +149,9 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
             jax.ShapeDtypeStruct((B, KH, G, D), q.dtype),
             jax.ShapeDtypeStruct((B, KH, G), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
         name="moska_paged_decode_attn",
     )(tbl, lens, qg, k_pool, v_pool)
 

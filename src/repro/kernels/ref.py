@@ -19,21 +19,21 @@ def shared_chunk_attention_ref(qd: jax.Array, k: jax.Array, v: jax.Array,
                                ) -> Tuple[jax.Array, jax.Array]:
     """The batched per-chunk GEMM attention (paper Fig. 2a).
 
-    qd: (E, cap, H, D) dispatched queries; k/v: (E, C, KH, D);
+    qd: (E, cap, H, D) dispatched queries; k/v: (E, KH, C, D);
     qmask: (E, cap) bool. Non-causal. Returns (out (E,cap,H,D),
     lse (E,cap,H) fp32; -inf rows where qmask is False).
     """
     E, cap, H, D = qd.shape
-    KH = k.shape[2]
+    KH = k.shape[1]
     G = H // KH
     scale = 1.0 / math.sqrt(D)
     qg = qd.reshape(E, cap, KH, G, D)
-    s = jnp.einsum("eckgd,eskd->eckgs", qg.astype(jnp.float32),
+    s = jnp.einsum("eckgd,eksd->eckgs", qg.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     m = jnp.max(s, axis=-1)
     p = jnp.exp(s - m[..., None])
     l = jnp.sum(p, axis=-1)
-    o = jnp.einsum("eckgs,eskd->eckgd", p, v.astype(jnp.float32))
+    o = jnp.einsum("eckgs,eksd->eckgd", p, v.astype(jnp.float32))
     o = o / jnp.maximum(l, 1e-37)[..., None]
     lse = m + jnp.log(jnp.maximum(l, 1e-37))
     lse = jnp.where(qmask[:, :, None, None], lse, NEG_INF)

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from repro.kernels import resolve_interpret
 
 
 def _kernel(q_ref, e_ref, s_ref, *, scale: float):
@@ -29,7 +29,8 @@ def _kernel(q_ref, e_ref, s_ref, *, scale: float):
 @functools.partial(jax.jit, static_argnames=("block_g", "block_e",
                                              "interpret"))
 def router_scores(q: jax.Array, emb: jax.Array, *, block_g: int = 128,
-                  block_e: int = 512, interpret: bool = True) -> jax.Array:
+                  block_e: int = 512,
+                  interpret: bool | None = None) -> jax.Array:
     """q: (G, H, D); emb: (E, KH, D) -> scores (G, E) fp32.
 
     Each query head scores its kv head's embedding (GQA-aligned); summing
@@ -56,8 +57,8 @@ def router_scores(q: jax.Array, emb: jax.Array, *, block_g: int = 128,
         ],
         out_specs=pl.BlockSpec((block_g, block_e), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((G, E), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
         name="moska_router_scores",
     )(qf, ef)

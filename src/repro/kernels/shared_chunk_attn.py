@@ -1,18 +1,20 @@
 """Pallas TPU kernel: Shared KV Attention — the paper's GEMM (Fig. 2a).
 
-One grid cell = (shared chunk e, kv head kh, kv tile c). The dispatched
-query batch for chunk ``e`` — (cap, G, D), all concurrent requests that
-routed here — is multiplied against the chunk's KV tile (C_blk, D) on the
-MXU: exactly the memory-bound-GEMV -> compute-bound-GEMM transformation.
-Online softmax accumulates across kv tiles in VMEM scratch; the final tile
-normalizes and writes (out, lse).
+One grid cell = (shared chunk e, kv head kh, query tile i, kv tile c). The
+dispatched query rows for chunk ``e`` and kv head ``kh`` — every
+concurrent request that routed here, times the kv head's G query heads,
+flattened to (cap*G, D) — are multiplied against the chunk's KV tile
+(block_c, D) on the MXU: exactly the memory-bound-GEMV -> compute-bound-
+GEMM transformation. Online softmax accumulates across kv tiles in VMEM
+scratch; the last kv tile normalizes and writes (out, lse).
 
-Hardware adaptation (DESIGN.md §3): tiles are MXU-aligned — cap*G and C_blk
-are multiples of 128 at production sizes, D=head_dim is the contraction.
-VMEM working set per cell ≈ capG*D (q) + C_blk*D*2 (kv) + capG*C_blk (p)
-+ capG*(D+2) (scratch) floats; with cap*G=256, C_blk=512, D=128 that is
-~1.1 MB — well inside the ~16 MB v5e VMEM budget, leaving room for
-double-buffered pipelining.
+Block layout (the TPU's (8, 128) rule: the last two dims of every block
+are multiples of (8, 128) or equal the array's own): the store is laid out
+per kv head, k/v (E, KH, C, D), so a KV block is (1, 1, block_c, D) with
+block_c a multiple of 8 and D the full head dim. Query rows and outputs
+are (E, KH, cap*G, D) blocks of (1, 1, block_q, D); the LSE is
+(E, KH, cap*G, 1). Dispatch validity (qmask) is applied after the call, so
+no lane-sparse mask block enters the kernel.
 """
 from __future__ import annotations
 
@@ -24,20 +26,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
+# query rows per grid cell: bounds the (rows, block_c) score tile in VMEM
+# when a prefill block dispatches thousands of rows to one chunk
+BLOCK_Q = 256
 
 
-def _kernel(qm_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-            m_scr, l_scr, acc_scr, *, nc: int, scale: float, tot_c: int):
-    c = pl.program_id(2)
-
-    q = q_ref[0, 0].astype(jnp.float32)        # (cap, G, D)
-    cap, G, D = q.shape
-    qf = q.reshape(cap * G, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)      # (C_blk, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)      # (C_blk, D)
+def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
+            nc: int, scale: float, tot_c: int):
+    c = pl.program_id(3)
 
     @pl.when(c == 0)
     def _init():
@@ -45,190 +44,96 @@ def _kernel(qm_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    s = jax.lax.dot_general(qf, k, (((1,), (1,)), ((), ())),
+    q = q_ref[0, 0]                              # (block_q, D)
+    k = k_ref[0, 0]                              # (block_c, D)
+    v = v_ref[0, 0]
+    # explicit precision: Mosaic rejects a bf16 matmul at the HIGHEST
+    # precision a caller may have set as the default
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.DEFAULT,
                             preferred_element_type=jnp.float32) * scale
-    # mask the ragged tail tile (C not a multiple of block_c): OOB padding
     blk = k.shape[0]
-    pos = c * blk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(pos < tot_c, s, NEG_INF)
-    vpos = c * blk + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
-    v = jnp.where(vpos < tot_c, v, 0.0)
-    m_prev = m_scr[...]                          # (capG, 1)
-    m_blk = jnp.max(s, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_blk)
-    p = jnp.exp(s - m_new)                       # (capG, C_blk)
-    corr = jnp.exp(m_prev - m_new)               # (capG, 1)
-    l_new = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-    pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    acc_new = acc_scr[...] * corr + pv
+    if tot_c % blk:
+        # ragged tail tile (C not a multiple of block_c): the padding past
+        # C is unspecified, so mask the scores and zero the values
+        pos = c * blk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < tot_c, s, NEG_INF)
+        vpos = c * blk + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+        v = jnp.where(vpos < tot_c, v, jnp.zeros_like(v))
+    m_prev = m_scr[...]                          # (block_q, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)                       # (block_q, block_c)
+    corr = jnp.exp(m_prev - m_new)
+    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.float32)
     m_scr[...] = m_new
-    l_scr[...] = l_new
-    acc_scr[...] = acc_new
 
     @pl.when(c == nc - 1)
     def _finalize():
-        qmask = qm_ref[0]                        # (cap,) int32 validity
-        l_fin = l_scr[...]
-        l_safe = jnp.maximum(l_fin, 1e-37)
-        out = (acc_scr[...] / l_safe).reshape(cap, G, D)
-        valid = qmask[:, None, None] > 0
-        o_ref[0, 0] = jnp.where(valid, out, 0.0).astype(o_ref.dtype)
-        lse = (m_scr[...] + jnp.log(l_safe)).reshape(cap, G)
-        lse_ref[0, 0] = jnp.where(qmask[:, None] > 0, lse, NEG_INF)
+        l_safe = jnp.maximum(l_scr[...], 1e-37)
+        o_ref[0, 0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_scr[...] + jnp.log(l_safe)
 
 
 @functools.partial(jax.jit, static_argnames=("block_c", "interpret"))
 def shared_chunk_attention(qd: jax.Array, k: jax.Array, v: jax.Array,
                            qmask: jax.Array, *, block_c: int = 512,
-                           interpret: bool = True):
-    """qd: (E, cap, H, D); k/v: (E, C, KH, D); qmask: (E, cap) bool.
+                           interpret: bool | None = None):
+    """qd: (E, cap, H, D); k/v: (E, KH, C, D); qmask: (E, cap) bool.
 
-    Returns (out (E, cap, H, D), lse (E, cap, H) fp32). Grid is
-    (E, KH, C/block_c); each kv head serves its G = H // KH query heads.
+    Returns (out (E, cap, H, D), lse (E, cap, H) fp32); masked slots carry
+    a zero output and a -inf (``NEG_INF``) lse. Grid is
+    (E, KH, cap*G / BLOCK_Q, C / block_c); each kv head serves its
+    G = H // KH query heads. ``interpret=None`` interprets the kernel body
+    on the CPU backend only (``repro.kernels.resolve_interpret``).
     """
     E, cap, H, D = qd.shape
-    _, C, KH, _ = k.shape
+    _, KH, C, _ = k.shape
     G = H // KH
+    R = cap * G
     block_c = min(block_c, C)
+    block_q = min(BLOCK_Q, R)
     nc = pl.cdiv(C, block_c)
     scale = 1.0 / math.sqrt(D)
 
-    # regroup queries by kv head: (E, KH, cap, G, D)
-    qg = qd.reshape(E, cap, KH, G, D).transpose(0, 2, 1, 3, 4)
-    qm = qmask.astype(jnp.int32)
+    # regroup query rows by kv head: (E, KH, cap*G, D)
+    qg = qd.reshape(E, cap, KH, G, D).transpose(0, 2, 1, 3, 4).reshape(
+        E, KH, R, D)
 
-    grid = (E, KH, nc)
     out, lse = pl.pallas_call(
         functools.partial(_kernel, nc=nc, scale=scale, tot_c=C),
-        grid=grid,
+        grid=(E, KH, pl.cdiv(R, block_q), nc),
         in_specs=[
-            pl.BlockSpec((1, cap), lambda e, h, c: (e, 0)),
-            pl.BlockSpec((1, 1, cap, G, D), lambda e, h, c: (e, h, 0, 0, 0)),
-            pl.BlockSpec((1, block_c, 1, D), lambda e, h, c: (e, c, h, 0)),
-            pl.BlockSpec((1, block_c, 1, D), lambda e, h, c: (e, c, h, 0)),
+            pl.BlockSpec((1, 1, block_q, D), lambda e, h, i, c: (e, h, i, 0)),
+            pl.BlockSpec((1, 1, block_c, D), lambda e, h, i, c: (e, h, c, 0)),
+            pl.BlockSpec((1, 1, block_c, D), lambda e, h, i, c: (e, h, c, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, cap, G, D), lambda e, h, c: (e, h, 0, 0, 0)),
-            pl.BlockSpec((1, 1, cap, G), lambda e, h, c: (e, h, 0, 0)),
+            pl.BlockSpec((1, 1, block_q, D), lambda e, h, i, c: (e, h, i, 0)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda e, h, i, c: (e, h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((E, KH, cap, G, D), qd.dtype),
-            jax.ShapeDtypeStruct((E, KH, cap, G), jnp.float32),
+            jax.ShapeDtypeStruct((E, KH, R, D), qd.dtype),
+            jax.ShapeDtypeStruct((E, KH, R, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((cap * G, 1), jnp.float32),
-            pltpu.VMEM((cap * G, 1), jnp.float32),
-            pltpu.VMEM((cap * G, D), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+        interpret=resolve_interpret(interpret),
         name="moska_shared_chunk_attn",
-    )(qm, qg, k, v)
+    )(qg, k, v)
 
-    out = out.transpose(0, 2, 1, 3, 4).reshape(E, cap, H, D)
-    lse = lse.transpose(0, 2, 1, 3).reshape(E, cap, H)
-    return out, lse
-
-
-# ---------------------------------------------------------------------------
-# int8-quantized shared store (beyond-paper; FP8 parity on TPU): the kernel
-# reads int8 KV tiles from HBM (half the bandwidth of bf16) and dequantizes
-# in-register inside VMEM — the XLA/jnp path cannot express this fusion.
-# ---------------------------------------------------------------------------
-
-def _kernel_q8(qm_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, lse_ref,
-               m_scr, l_scr, acc_scr, *, nc: int, scale: float, tot_c: int):
-    c = pl.program_id(2)
-
-    q = q_ref[0, 0].astype(jnp.float32)        # (cap, G, D)
-    cap, G, D = q.shape
-    qf = q.reshape(cap * G, D)
-    # in-register dequantization of the int8 tiles
-    ksc = ks_ref[0, :, 0].astype(jnp.float32)   # (C_blk,)
-    vsc = vs_ref[0, :, 0].astype(jnp.float32)
-    k = k_ref[0, :, 0].astype(jnp.float32) * ksc[:, None]
-    v = v_ref[0, :, 0].astype(jnp.float32) * vsc[:, None]
-
-    @pl.when(c == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    s = jax.lax.dot_general(qf, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    blk = k.shape[0]
-    pos = c * blk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(pos < tot_c, s, NEG_INF)
-    vpos = c * blk + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
-    v = jnp.where(vpos < tot_c, v, 0.0)
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
-
-    @pl.when(c == nc - 1)
-    def _finalize():
-        qmask = qm_ref[0]
-        l_safe = jnp.maximum(l_scr[...], 1e-37)
-        out = (acc_scr[...] / l_safe).reshape(cap, G, D)
-        valid = qmask[:, None, None] > 0
-        o_ref[0, 0] = jnp.where(valid, out, 0.0).astype(o_ref.dtype)
-        lse = (m_scr[...] + jnp.log(l_safe)).reshape(cap, G)
-        lse_ref[0, 0] = jnp.where(qmask[:, None] > 0, lse, NEG_INF)
-
-
-@functools.partial(jax.jit, static_argnames=("block_c", "interpret"))
-def shared_chunk_attention_q8(qd: jax.Array, k: jax.Array, v: jax.Array,
-                              k_scale: jax.Array, v_scale: jax.Array,
-                              qmask: jax.Array, *, block_c: int = 512,
-                              interpret: bool = True):
-    """int8 variant. k/v: (E, C, KH, D) int8; scales: (E, C, KH) f32."""
-    E, cap, H, D = qd.shape
-    _, C, KH, _ = k.shape
-    G = H // KH
-    block_c = min(block_c, C)
-    nc = pl.cdiv(C, block_c)
-    scale = 1.0 / math.sqrt(D)
-    qg = qd.reshape(E, cap, KH, G, D).transpose(0, 2, 1, 3, 4)
-    qm = qmask.astype(jnp.int32)
-
-    out, lse = pl.pallas_call(
-        functools.partial(_kernel_q8, nc=nc, scale=scale, tot_c=C),
-        grid=(E, KH, nc),
-        in_specs=[
-            pl.BlockSpec((1, cap), lambda e, h, c: (e, 0)),
-            pl.BlockSpec((1, 1, cap, G, D), lambda e, h, c: (e, h, 0, 0, 0)),
-            pl.BlockSpec((1, block_c, 1, D), lambda e, h, c: (e, c, h, 0)),
-            pl.BlockSpec((1, block_c, 1, D), lambda e, h, c: (e, c, h, 0)),
-            pl.BlockSpec((1, block_c, 1), lambda e, h, c: (e, c, h)),
-            pl.BlockSpec((1, block_c, 1), lambda e, h, c: (e, c, h)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, cap, G, D), lambda e, h, c: (e, h, 0, 0, 0)),
-            pl.BlockSpec((1, 1, cap, G), lambda e, h, c: (e, h, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((E, KH, cap, G, D), jnp.bfloat16),
-            jax.ShapeDtypeStruct((E, KH, cap, G), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((cap * G, 1), jnp.float32),
-            pltpu.VMEM((cap * G, 1), jnp.float32),
-            pltpu.VMEM((cap * G, D), jnp.float32),
-        ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="moska_shared_chunk_attn_q8",
-    )(qm, qg, k, v, k_scale, v_scale)
-
-    out = out.transpose(0, 2, 1, 3, 4).reshape(E, cap, H, D)
-    lse = lse.transpose(0, 2, 1, 3).reshape(E, cap, H)
-    return out, lse
+    out = out.reshape(E, KH, cap, G, D).transpose(0, 2, 1, 3, 4).reshape(
+        E, cap, H, D)
+    lse = lse.reshape(E, KH, cap, G).transpose(0, 2, 1, 3).reshape(E, cap, H)
+    valid = qmask[:, :, None]
+    return (jnp.where(valid[..., None], out, jnp.zeros_like(out)),
+            jnp.where(valid, lse, NEG_INF))

@@ -7,7 +7,7 @@ hardware.
 For every (architecture x input shape) on the 16x16 single-pod mesh AND the
 2x16x16 multi-pod mesh:
 
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered  = jax.jit(step, ...).lower(*input_specs(arch, shape))
         compiled = lowered.compile()
         print(compiled.memory_analysis())   # proves it fits
@@ -48,7 +48,7 @@ def run_one(arch: str, shape: str, multi_pod: bool,
     record = {"arch": arch, "shape": shape, "mesh": mesh_name,
               "variant": variant, "status": "ok"}
     try:
-        with mesh:
+        with jax.set_mesh(mesh):
             spec = ispecs.build(arch, shape, mesh, variant=variant)
             set_rules(spec.rules)
             try:

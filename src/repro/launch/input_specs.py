@@ -110,12 +110,12 @@ _CACHE_AXES: Dict[str, Tuple[Optional[str], ...]] = {
 }
 
 _STORE_AXES = {
-    "k": (None, "chunks", "chunk_seq", "kv_heads", None),
-    "v": (None, "chunks", "chunk_seq", "kv_heads", None),
+    "k": (None, "chunks", "kv_heads", "chunk_seq", None),
+    "v": (None, "chunks", "kv_heads", "chunk_seq", None),
     "emb": (None, "chunks", "kv_heads", None),
     "chunk_positions": (None,),
-    "k_scale": (None, "chunks", "chunk_seq", "kv_heads"),
-    "v_scale": (None, "chunks", "chunk_seq", "kv_heads"),
+    "k_scale": (None, "chunks", "kv_heads", "chunk_seq"),
+    "v_scale": (None, "chunks", "kv_heads", "chunk_seq"),
 }
 
 

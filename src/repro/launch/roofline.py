@@ -19,7 +19,32 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
-from repro.launch.mesh import HW
+# the chip the dry-run roofline terms are priced on
+V5E = "TPU v5 lite"
+
+# Published per-chip peaks, keyed by ``jax.devices()[i].device_kind``.
+# Source for "TPU v5 lite" (TPU v5e): Google Cloud documentation, "TPU v5e"
+# system architecture page — 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+# 1,600 Gbit/s chip-to-chip interconnect (4 links of 50 GB/s).
+PEAKS: Dict[str, Dict[str, float]] = {
+    V5E: {
+        "peak_flops_bf16": 197e12,      # FLOP/s
+        "hbm_bw": 819e9,                # bytes/s
+        "ici_link_bw": 50e9,            # bytes/s per link
+        "hbm_bytes": 16e9,
+    },
+}
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Peak rates of one chip of ``device_kind``; an unknown kind raises
+    (there is no default chip)."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
 
 _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                 "collective-permute")
@@ -84,15 +109,15 @@ class Roofline:
 
     @property
     def compute_s(self) -> float:
-        return self.flops_per_chip / HW["peak_flops_bf16"]
+        return self.flops_per_chip / peaks(V5E)["peak_flops_bf16"]
 
     @property
     def memory_s(self) -> float:
-        return self.bytes_per_chip / HW["hbm_bw"]
+        return self.bytes_per_chip / peaks(V5E)["hbm_bw"]
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes_per_chip / HW["ici_link_bw"]
+        return self.collective_bytes_per_chip / peaks(V5E)["ici_link_bw"]
 
     @property
     def dominant(self) -> str:

@@ -6,9 +6,12 @@ Registers a synthetic domain corpus (precomputed shared KV chunks), submits
 a stream of requests against it, and reports scheduler/throughput metrics
 from the process-global observability registry (``repro.obs``). The default
 invocation is the fast dry-run path: a reduced config small enough for CPU
-smoke runs; pass ``--full`` for the unreduced architecture. On TPU hardware
-the same engine runs under make_production_mesh with SERVE_RULES (unique KV
-batch-sharded = Unique pool; chunks data-sharded = Shared pool).
+smoke runs; pass ``--full`` for the published architecture. Everything runs
+on JAX's default device — one chip, or the CPU — with no mesh and no
+sharding rules; ``--kernel pallas`` runs the decode step's shared attention
+through the Pallas kernel, compiled for a TPU and interpreted on the CPU.
+JAX's persistent compilation cache is kept where
+``JAX_COMPILATION_CACHE_DIR`` says, else in ``<repo>/.jax_cache``.
 
 ``--metrics-out PATH`` dumps the full registry at exit — scheduler
 occupancy/affinity, dispatch capacity-utilization, decode-latency
@@ -28,10 +31,9 @@ from repro import obs
 from repro.configs import get_config
 from repro.core.scheduler import wave_stats
 from repro.data.pipeline import CorpusSpec, synthesize_corpus
-from repro.launch.mesh import make_host_mesh
+from repro.launch.compile_cache import init_compile_cache
 from repro.models.model import build_model
 from repro.serving.engine import EngineConfig, ServingEngine
-from repro.sharding import SERVE_RULES, set_rules
 
 
 def main(argv=None) -> dict:
@@ -48,7 +50,9 @@ def main(argv=None) -> dict:
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--new-tokens", type=int, default=16)
     ap.add_argument("--corpus-tokens", type=int, default=512)
-    ap.add_argument("--kernel", default=None, choices=[None, "pallas"])
+    ap.add_argument("--kernel", default="jnp", choices=["jnp", "pallas"],
+                    help="decode-step shared attention: the jnp path "
+                         "(default) or the Pallas kernel")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--no-donate", action="store_true",
                     help="disable cache donation (copying decode steps; "
@@ -116,6 +120,11 @@ def main(argv=None) -> dict:
     cfg = get_config(args.arch)
     if not args.full:
         cfg = cfg.reduced()
+    if args.corpus_tokens < cfg.moska.chunk_size:
+        ap.error(f"--corpus-tokens {args.corpus_tokens} is shorter than one "
+                 f"shared chunk of {cfg.name} ({cfg.moska.chunk_size} "
+                 "tokens)")
+    init_compile_cache()
 
     if args.prefill_buckets == "none":
         buckets = None
@@ -128,7 +137,8 @@ def main(argv=None) -> dict:
         model = build_model(cfg)
         params = model.init(jax.random.PRNGKey(args.seed))
         eng = ServingEngine(cfg, params, EngineConfig(
-            max_slots=args.slots, max_seq=args.max_seq, kernel=args.kernel,
+            max_slots=args.slots, max_seq=args.max_seq,
+            kernel=None if args.kernel == "jnp" else args.kernel,
             donate_cache=not args.no_donate, prefill_buckets=buckets,
             kv_layout=args.kv_layout, block_size=args.block_size,
             num_blocks=args.num_blocks,
@@ -201,6 +211,9 @@ def main(argv=None) -> dict:
     if args.metrics_out:
         obs.dump(args.metrics_out, reg)
         print(f"metrics registry -> {args.metrics_out}")
+    # greedy tokens per request, in submission order (returned, not printed)
+    summary["generations"] = [r.generated
+                              for r in sorted(done, key=lambda r: r.uid)]
     return summary
 
 

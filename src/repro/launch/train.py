@@ -50,7 +50,7 @@ def main() -> None:
     if args.host_mesh or jax.device_count() > 1:
         mesh = (make_host_mesh() if args.host_mesh
                 else make_production_mesh(multi_pod=args.multi_pod))
-        with mesh:
+        with jax.set_mesh(mesh):
             set_rules(TRAIN_RULES)
             try:
                 out = train(cfg, loop_cfg, batches)

@@ -132,7 +132,8 @@ def _layer_prefill(cfg: ModelConfig, x: jax.Array, lp: Params,
                    shared: Optional[Tuple[jax.Array, jax.Array, jax.Array]],
                    q_offset: jax.Array,
                    true_len: Optional[jax.Array] = None,
-                   layer_idx: Optional[jax.Array] = None
+                   layer_idx: Optional[jax.Array] = None,
+                   kernel: Optional[str] = None
                    ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Prefill layer: causal attention + cache write + optional MoSKA path.
 
@@ -170,7 +171,8 @@ def _layer_prefill(cfg: ModelConfig, x: jax.Array, lp: Params,
         ctx = MA.MoskaLayerContext(sk, sv, routing)
         o = MA.moska_prefill_attention(
             q, k, v, ctx, cfg.moska, q_offset=q_offset,
-            window=cfg.attn_window, route_block=rb, layer_idx=layer_idx)
+            window=cfg.attn_window, route_block=rb, kernel=kernel,
+            layer_idx=layer_idx)
     else:
         o = L.flash_attention(q, k, v, causal=True, q_offset=q_offset,
                               kv_offset=q_offset, window=cfg.attn_window)
@@ -369,8 +371,7 @@ def _shared_xs(cfg: ModelConfig, store: Optional[SharedKVStore]):
 
 
 def _shared_layer(sh, dtype):
-    """Per-layer store slices; dequantizes int8 KV (the Pallas kernel does
-    this in-register on TPU; the jnp path materializes the dequant)."""
+    """Per-layer store slices; dequantizes an int8 store to ``dtype``."""
     sk, sv, semb = sh["k"], sh["v"], sh["emb"]
     if "ks" in sh:
         sk = sk.astype(dtype) * sh["ks"][..., None].astype(dtype)
@@ -382,13 +383,15 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
             cache: KVCache, store: Optional[SharedKVStore] = None,
             frontend_embeds: Optional[jax.Array] = None,
             start_pos: int = 0,
-            true_len: Optional[jax.Array] = None) -> Tuple[jax.Array, KVCache]:
+            true_len: Optional[jax.Array] = None,
+            kernel: Optional[str] = None) -> Tuple[jax.Array, KVCache]:
     """Process the unique prefix; returns (last-token logits, filled cache).
 
     ``true_len`` (traced scalar ok): real prompt length when ``tokens`` is
     right-padded to a prefill bucket — logits are taken at position
     ``true_len - 1`` and the cache lengths record ``true_len``. Not
-    supported together with ``frontend_embeds``.
+    supported together with ``frontend_embeds``. ``kernel`` selects the
+    shared-attention implementation (None: jnp; 'pallas').
     """
     if true_len is not None and frontend_embeds is not None:
         raise ValueError("true_len is not supported with frontend_embeds")
@@ -405,7 +408,8 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
             sh = None
         x, kc, vc, _ = _layer_prefill(cfg, x, lp, positions, kc, vc, sh,
                                       jnp.asarray(start_pos),
-                                      true_len=true_len, layer_idx=li)
+                                      true_len=true_len, layer_idx=li,
+                                      kernel=kernel)
         return x, (kc, vc)
 
     lidx = jnp.arange(cfg.num_layers)
@@ -507,7 +511,8 @@ def _layer_prefill_chunk(cfg: ModelConfig, x: jax.Array, lp: Params,
                          kc: jax.Array, vc: jax.Array,
                          base: jax.Array, chunk_len: jax.Array,
                          shared, start_pos: jax.Array,
-                         layer_idx: Optional[jax.Array] = None
+                         layer_idx: Optional[jax.Array] = None,
+                         kernel: Optional[str] = None
                          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One chunk of a long prompt against the growing context view.
 
@@ -545,7 +550,7 @@ def _layer_prefill_chunk(cfg: ModelConfig, x: jax.Array, lp: Params,
             return_lse=True)
         part = sa.shared_attention_batched(
             q.reshape(B * nb, rb, H, D), sk, sv, routing,
-            capacity_factor=cfg.moska.query_capacity_factor,
+            capacity_factor=cfg.moska.query_capacity_factor, kernel=kernel,
             layer_idx=layer_idx)
         o_s = part.out.reshape(B, C, H, D)
         lse_s = part.lse.reshape(B, C, H)
@@ -565,7 +570,8 @@ def _layer_prefill_chunk(cfg: ModelConfig, x: jax.Array, lp: Params,
 def prefill_chunk(cfg: ModelConfig, params: Params, tokens: jax.Array,
                   cache: KVCache, store: Optional[SharedKVStore] = None,
                   start_pos=0,
-                  chunk_len: Optional[jax.Array] = None
+                  chunk_len: Optional[jax.Array] = None,
+                  kernel: Optional[str] = None
                   ) -> Tuple[jax.Array, KVCache]:
     """Process one chunk of a long prompt; call repeatedly to prefill
     prompts past the largest bucket with a bounded jit cache.
@@ -596,7 +602,7 @@ def prefill_chunk(cfg: ModelConfig, params: Params, tokens: jax.Array,
             sh = None
         x, kc, vc = _layer_prefill_chunk(cfg, x, lp, positions, kc, vc,
                                          base, chunk_len, sh, start,
-                                         layer_idx=li)
+                                         layer_idx=li, kernel=kernel)
         return x, (kc, vc)
 
     lidx = jnp.arange(cfg.num_layers)

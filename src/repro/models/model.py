@@ -60,10 +60,13 @@ class Model:
                                      abstract=abstract)
 
     def prefill(self, params, tokens, cache, store=None,
-                frontend_embeds=None, start_pos: int = 0, true_len=None):
-        # true_len: real prompt length for bucket-padded serving prefill
-        # (dense-family only — the engine's zero-copy hot path)
+                frontend_embeds=None, start_pos: int = 0, true_len=None,
+                kernel: Optional[str] = None):
+        # true_len: real prompt length for bucket-padded serving prefill;
+        # kernel: shared-attention implementation (dense family only)
         kw = {} if true_len is None else {"true_len": true_len}
+        if self.cfg.family in (DENSE, VLM, MOE):
+            kw["kernel"] = kernel
         if self.cfg.family in (VLM, AUDIO):
             return self._impl.prefill(self.cfg, params, tokens, cache,
                                       store=store,
@@ -102,11 +105,12 @@ class Model:
                                             store=store, kernel=kernel)
 
     def prefill_chunk(self, params, tokens, cache, store=None,
-                      start_pos=0, chunk_len=None):
+                      start_pos=0, chunk_len=None,
+                      kernel: Optional[str] = None):
         self._require_paged("prefill_chunk")
         return self._impl.prefill_chunk(self.cfg, params, tokens, cache,
                                         store=store, start_pos=start_pos,
-                                        chunk_len=chunk_len)
+                                        chunk_len=chunk_len, kernel=kernel)
 
 
 def build_model(cfg: ModelConfig) -> Model:

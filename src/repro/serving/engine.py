@@ -139,7 +139,9 @@ class EngineConfig:
     eos_id: int = -1           # -1: never stop early
     greedy: bool = True
     mem_budget_bytes: float = float("inf")
-    kernel: Optional[str] = None    # None|'pallas' for shared attention
+    # shared attention in prefill and decode: None (jnp) or 'pallas' (the
+    # kernel: compiled on a TPU, interpreted on the CPU backend)
+    kernel: Optional[str] = None
     cache_dtype: Any = jnp.bfloat16
     # record dispatch-density metrics from inside the jit'd decode step
     # (trace-time switch; adds host callbacks to the compiled program)
@@ -392,7 +394,7 @@ class ServingEngine:
         logits, slot_cache = self.model.prefill(
             params, tokens, slot_cache,
             store=store if use_store else None,
-            start_pos=start, true_len=true_len)
+            start_pos=start, true_len=true_len, kernel=self.ecfg.kernel)
         first = jnp.argmax(logits[0]).astype(jnp.int32)
         return first, slot_cache
 
@@ -426,7 +428,7 @@ class ServingEngine:
         scratch context ``ctx``; returns (last-real-token argmax, ctx)."""
         logits, ctx = self.model.prefill_chunk(
             params, tokens, ctx, store=store if use_store else None,
-            start_pos=start, chunk_len=chunk_len)
+            start_pos=start, chunk_len=chunk_len, kernel=self.ecfg.kernel)
         first = jnp.argmax(logits[0]).astype(jnp.int32)
         return first, ctx
 

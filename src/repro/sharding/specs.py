@@ -100,19 +100,15 @@ def spec(names: Sequence[Optional[str]],
     return _resolve(rules, names, axes)
 
 
-def _current_mesh() -> Optional[jax.sharding.Mesh]:
-    try:
-        from jax._src import mesh as mesh_lib
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    return None
+def _current_mesh() -> Optional[jax.sharding.AbstractMesh]:
+    """The mesh installed with ``jax.set_mesh``, or None outside one."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def logical_sharding_constraint(x: jax.Array, *names: Optional[str]) -> jax.Array:
-    """with_sharding_constraint by logical names; identity w/o rules+mesh."""
+    """with_sharding_constraint by logical names; identity unless rules
+    are set and a mesh is installed with ``jax.set_mesh``."""
     rules = current_rules()
     if rules is None:
         return x
@@ -124,8 +120,7 @@ def logical_sharding_constraint(x: jax.Array, *names: Optional[str]) -> jax.Arra
         names = names[len(names) - x.ndim:]
     elif len(names) < x.ndim:
         names = (None,) * (x.ndim - len(names)) + tuple(names)
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    ps = _resolve(rules, names, mesh.axis_names, x.shape, sizes)
+    ps = _resolve(rules, names, mesh.axis_names, x.shape, dict(mesh.shape))
     return jax.lax.with_sharding_constraint(x, ps)
 
 

@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.launch.hlo_cost import HloModule, analyze_hlo, _shape_bytes
-from repro.launch.roofline import collective_bytes
+from repro.launch.roofline import V5E, collective_bytes, peaks
 
 
 def _compile(f, *args):
@@ -80,3 +80,13 @@ def test_module_entry_detection():
     m = HloModule(_compile(f, x).as_text())
     assert m.entry is not None
     assert m.entry in m.computations
+
+
+def test_peaks_keyed_by_device_kind():
+    """The v5e's published peaks are found by its device_kind; any other
+    kind raises instead of falling back to a default chip."""
+    v5e = peaks(V5E)
+    assert v5e["peak_flops_bf16"] == 197e12 and v5e["hbm_bw"] == 819e9
+    for kind in ("cpu", "TPU v4", "TPU v6 lite"):
+        with pytest.raises(KeyError):
+            peaks(kind)

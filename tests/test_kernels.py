@@ -5,8 +5,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops
 from repro.kernels import ref as kref
+from repro.kernels.decode_attn import decode_attention
+from repro.kernels.lse_merge import lse_merge
+from repro.kernels.paged_decode_attn import paged_decode_attention
+from repro.kernels.router_score import router_scores
+from repro.kernels.shared_chunk_attn import shared_chunk_attention
 
 KEY = jax.random.PRNGKey(0)
 
@@ -31,10 +35,10 @@ def _tols(dtype):
 ])
 def test_shared_chunk_attention(dtype, E, cap, H, KH, D, C, blk):
     qd = _rand(jax.random.fold_in(KEY, 1), (E, cap, H, D), dtype)
-    k = _rand(jax.random.fold_in(KEY, 2), (E, C, KH, D), dtype)
-    v = _rand(jax.random.fold_in(KEY, 3), (E, C, KH, D), dtype)
+    k = _rand(jax.random.fold_in(KEY, 2), (E, KH, C, D), dtype)
+    v = _rand(jax.random.fold_in(KEY, 3), (E, KH, C, D), dtype)
     qm = jax.random.bernoulli(jax.random.fold_in(KEY, 4), 0.7, (E, cap))
-    o1, l1 = ops.shared_chunk_attention(qd, k, v, qm, block_c=blk)
+    o1, l1 = shared_chunk_attention(qd, k, v, qm, block_c=blk)
     o2, l2 = kref.shared_chunk_attention_ref(qd, k, v, qm)
     np.testing.assert_allclose(np.float32(o1), np.float32(o2),
                                **_tols(dtype))
@@ -57,7 +61,7 @@ def test_decode_attention(dtype, B, H, KH, D, S, blk):
     k = _rand(jax.random.fold_in(KEY, 2), (B, S, KH, D), dtype)
     v = _rand(jax.random.fold_in(KEY, 3), (B, S, KH, D), dtype)
     lens = jax.random.randint(jax.random.fold_in(KEY, 4), (B,), 1, S + 1)
-    o1, l1 = ops.decode_attention(q, k, v, lens, block_s=blk)
+    o1, l1 = decode_attention(q, k, v, lens, block_s=blk)
     o2, l2 = kref.decode_attention_ref(q, k, v, lens)
     np.testing.assert_allclose(np.float32(o1), np.float32(o2),
                                **_tols(dtype))
@@ -84,7 +88,7 @@ def test_paged_decode_attention(dtype, B, H, KH, D, N, bs, M):
     table = perm.reshape(B, M).astype(jnp.int32)
     lens = jax.random.randint(jax.random.fold_in(KEY, 15), (B,), 1,
                               M * bs + 1)
-    o1, l1 = ops.paged_decode_attention(q, k_pool, v_pool, table, lens)
+    o1, l1 = paged_decode_attention(q, k_pool, v_pool, table, lens)
     o2, l2 = paged_decode_attention_ref(q, k_pool, v_pool, table, lens)
     np.testing.assert_allclose(np.float32(o1), np.float32(o2),
                                **_tols(dtype))
@@ -105,7 +109,7 @@ def test_paged_decode_attention(dtype, B, H, KH, D, N, bs, M):
 def test_lse_merge(dtype, P, N, H, D, blk):
     outs = _rand(jax.random.fold_in(KEY, 5), (P, N, H, D), dtype)
     lses = jax.random.normal(jax.random.fold_in(KEY, 6), (P, N, H)) * 3
-    o1, l1 = ops.lse_merge(outs, lses, block_n=blk)
+    o1, l1 = lse_merge(outs, lses, block_n=blk)
     o2, l2 = kref.lse_merge_ref(outs, lses)
     np.testing.assert_allclose(np.float32(o1), np.float32(o2),
                                **_tols(dtype))
@@ -120,7 +124,7 @@ def test_lse_merge(dtype, P, N, H, D, blk):
 def test_router_scores(G, H, KH, D, E, bg, be):
     q = jax.random.normal(jax.random.fold_in(KEY, 7), (G, H, D))
     emb = jax.random.normal(jax.random.fold_in(KEY, 8), (E, KH, D))
-    s1 = ops.router_scores(q, emb, block_g=bg, block_e=be)
+    s1 = router_scores(q, emb, block_g=bg, block_e=be)
     s2 = kref.router_scores_ref(q, emb)
     np.testing.assert_allclose(s1, s2, rtol=2e-5, atol=2e-5)
 
@@ -133,38 +137,13 @@ def test_merge_of_decode_splits_equals_joint():
     k = _rand(jax.random.fold_in(KEY, 2), (B, S, KH, D), jnp.float32)
     v = _rand(jax.random.fold_in(KEY, 3), (B, S, KH, D), jnp.float32)
     full = jnp.full((B,), S, jnp.int32)
-    oj, _ = ops.decode_attention(q, k, v, full)
+    oj, _ = decode_attention(q, k, v, full)
     half = jnp.full((B,), S // 2, jnp.int32)
-    o1, l1 = ops.decode_attention(q, k[:, :S // 2], v[:, :S // 2], half)
-    o2, l2 = ops.decode_attention(q, k[:, S // 2:], v[:, S // 2:], half)
-    om, _ = ops.lse_merge(jnp.stack([o1, o2]), jnp.stack([l1, l2]))
+    o1, l1 = decode_attention(q, k[:, :S // 2], v[:, :S // 2], half)
+    o2, l2 = decode_attention(q, k[:, S // 2:], v[:, S // 2:], half)
+    om, _ = lse_merge(jnp.stack([o1, o2]), jnp.stack([l1, l2]))
     np.testing.assert_allclose(np.float32(om), np.float32(oj),
                                rtol=2e-5, atol=2e-5)
-
-
-@pytest.mark.parametrize("E,cap,H,KH,D,C,blk", [
-    (3, 8, 4, 2, 32, 64, 16), (2, 8, 8, 8, 64, 96, 64),
-])
-def test_shared_chunk_attention_int8(E, cap, H, KH, D, C, blk):
-    """int8-quantized store kernel (in-register dequant) vs dequantized
-    oracle, and bounded quantization error vs the fp reference."""
-    from repro.core.shared_kv import _quantize
-    from repro.kernels.shared_chunk_attn import shared_chunk_attention_q8
-    qd = _rand(jax.random.fold_in(KEY, 1), (E, cap, H, D), jnp.float32)
-    k = _rand(jax.random.fold_in(KEY, 2), (E, C, KH, D), jnp.float32)
-    v = _rand(jax.random.fold_in(KEY, 3), (E, C, KH, D), jnp.float32)
-    qm = jnp.ones((E, cap), bool)
-    kq, ks = _quantize(k)
-    vq, vs = _quantize(v)
-    o1, l1 = shared_chunk_attention_q8(qd, kq, vq, ks, vs, qm, block_c=blk)
-    kd = kq.astype(jnp.float32) * ks[..., None]
-    vd = vq.astype(jnp.float32) * vs[..., None]
-    o2, l2 = kref.shared_chunk_attention_ref(qd, kd, vd, qm)
-    np.testing.assert_allclose(np.float32(o1), np.float32(o2),
-                               rtol=2e-2, atol=2e-2)
-    np.testing.assert_allclose(l1, l2, rtol=1e-3, atol=1e-3)
-    o3, _ = kref.shared_chunk_attention_ref(qd, k, v, qm)
-    assert float(jnp.max(jnp.abs(np.float32(o1) - o3))) < 0.05
 
 
 def test_int8_store_end_to_end():

@@ -44,6 +44,12 @@ def _store(E=8, C=16, KH=2, D=32, layers=1, key=KEY):
     return build_store(k, v, C)
 
 
+def _tokens_major(x):
+    """One layer's store (E, KH, C, D) as corpus tokens (E*C, KH, D)."""
+    E, KH, C, D = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(E * C, KH, D)
+
+
 # ---------------------------------------------------------------------------
 # routing & dispatch
 # ---------------------------------------------------------------------------
@@ -126,8 +132,8 @@ def test_full_routing_equals_dense_attention():
     r = route(q[:, 0], store.emb[0], store.num_chunks)
     b = shared_attention_batched(q, store.k[0], store.v[0], r,
                                  capacity=5 * store.num_chunks)
-    kf = store.k[0].reshape(-1, 2, 32)
-    vf = store.v[0].reshape(-1, 2, 32)
+    kf = _tokens_major(store.k[0])
+    vf = _tokens_major(store.v[0])
     qg = q.reshape(5, 1, 2, 4, 32)
     s = jnp.einsum("gqkhd,skd->gqkhs", qg, kf) / math.sqrt(32)
     p = jax.nn.softmax(s, -1)
@@ -159,9 +165,9 @@ def _check_merge_exactness(G, K, seed):
     out = moska_decode_attention(q, kc, vc, lens, ctx,
                                  MoSKAConfig(top_k_chunks=E))
     for g in range(G):
-        keys = jnp.concatenate([store.k[0].reshape(-1, KH, D),
+        keys = jnp.concatenate([_tokens_major(store.k[0]),
                                 kc[g, :lens[g]]], 0)
-        vals = jnp.concatenate([store.v[0].reshape(-1, KH, D),
+        vals = jnp.concatenate([_tokens_major(store.v[0]),
                                 vc[g, :lens[g]]], 0)
         qg = q[g].reshape(KH, H // KH, D)
         s = jnp.einsum("khd,skd->khs", qg, keys) / math.sqrt(D)
@@ -266,3 +272,35 @@ def test_pallas_kernel_path_matches_jnp_path():
     l2, _ = dense.decode_step(cfg, params, toks[:, -1], cache, store=store,
                               kernel="pallas")
     np.testing.assert_allclose(l1, l2, rtol=2e-4, atol=2e-4)
+
+
+def test_pallas_kernel_prefill_matches_jnp_path():
+    """prefill (bucket-padded) and chunked prefill with kernel='pallas'
+    must equal the jnp shared path."""
+    cfg = dataclasses.replace(get_config("tinyllama-1.1b").reduced(),
+                              dtype="float32")
+    params = dense.init_params(cfg, jax.random.PRNGKey(0))
+    CL, S = 128, 16
+    ctoks = jax.random.randint(jax.random.fold_in(KEY, 7), (1, CL), 0,
+                               cfg.vocab_size)
+    ccache = init_kv_cache(cfg.num_layers, 1, CL, cfg.num_kv_heads,
+                           cfg.head_dim, jnp.float32)
+    _, ccache = dense.prefill(cfg, params, ctoks, ccache)
+    store = build_store(ccache.k[:, 0], ccache.v[:, 0],
+                        cfg.moska.chunk_size)
+    toks = jax.random.randint(jax.random.fold_in(KEY, 8), (1, S), 0,
+                              cfg.vocab_size)
+    outs = {}
+    for kern in (None, "pallas"):
+        cache = init_kv_cache(cfg.num_layers, 1, S, cfg.num_kv_heads,
+                              cfg.head_dim, jnp.float32)
+        lp, cp = dense.prefill(cfg, params, toks, cache, store=store,
+                               start_pos=CL, true_len=jnp.int32(S - 3),
+                               kernel=kern)
+        ctx = init_kv_cache(cfg.num_layers, 1, S, cfg.num_kv_heads,
+                            cfg.head_dim, jnp.float32)
+        lc, cc = dense.prefill_chunk(cfg, params, toks, ctx, store=store,
+                                     start_pos=CL, kernel=kern)
+        outs[kern] = (lp, cp.k, lc, cc.k)
+    for a, b in zip(outs[None], outs["pallas"]):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import make_host_mesh
 from repro.sharding import specs as sp
 
 
@@ -47,7 +48,7 @@ def test_apply_variant_overrides():
 
 
 def test_param_pspecs_name_mapping():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     params = {
         "layers": {
             "attn": {"wq": jnp.zeros((4, 64, 128))},   # stacked (L, d, h)
@@ -73,8 +74,7 @@ def test_lsc_identity_without_rules():
 
 def test_lsc_rank_alignment():
     """Names align from the right when rank differs (decode drops seq)."""
-    mesh = jax.make_mesh((1,), ("data",))
-    with mesh:
+    with jax.set_mesh(make_host_mesh()):
         sp.set_rules({"d_ff": "data"})
         try:
             x = jnp.ones((2, 8))

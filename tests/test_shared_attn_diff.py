@@ -21,16 +21,17 @@ import pytest
 from repro.core import router as router_lib
 from repro.core import shared_attention as sa
 from repro.core.router import Routing
-from repro.kernels import ops as kops
+from repro.kernels.shared_chunk_attn import shared_chunk_attention
 
 KEY = jax.random.PRNGKey(0)
 TOL = dict(rtol=3e-5, atol=3e-5)
 
 
 def _kv(E, C, KH, D, key=KEY):
-    k = jax.random.normal(jax.random.fold_in(key, 1), (E, C, KH, D),
+    """One layer's shared store, laid out per kv head: (E, KH, C, D)."""
+    k = jax.random.normal(jax.random.fold_in(key, 1), (E, KH, C, D),
                           jnp.float32)
-    v = jax.random.normal(jax.random.fold_in(key, 2), (E, C, KH, D),
+    v = jax.random.normal(jax.random.fold_in(key, 2), (E, KH, C, D),
                           jnp.float32)
     return k, v
 
@@ -113,8 +114,8 @@ def test_kernel_vs_reference_ragged(E, cap, H, KH, D, C, block_c):
     # ragged validity incl. one fully-empty chunk (chunk 0: no queries)
     qmask = jax.random.bernoulli(jax.random.fold_in(key, 4), 0.6, (E, cap))
     qmask = qmask.at[0].set(False)
-    out_k, lse_k = kops.shared_chunk_attention(qd, k, v, qmask,
-                                               block_c=block_c)
+    out_k, lse_k = shared_chunk_attention(qd, k, v, qmask,
+                                          block_c=block_c)
     out_r, lse_r = sa._chunk_batched_attention(qd[:, :, None], k, v, qmask)
     # masked slots: kernel zeroes the output, reference leaves it dangling
     # (both mark lse = -inf) — compare outputs on valid slots only

@@ -217,7 +217,8 @@ def test_disagg_shard_map_matches_batched():
     from repro.core import build_store, route, shared_attention_batched
     from repro.core.disagg import disaggregated_shared_attention
     from repro.configs.base import MoSKAConfig
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh()
     E, C, KH, D, H, B = 4, 8, 2, 16, 4, 3
     k = jax.random.normal(jax.random.fold_in(KEY, 1), (1, E * C, KH, D))
     v = jax.random.normal(jax.random.fold_in(KEY, 2), (1, E * C, KH, D))
@@ -225,7 +226,7 @@ def test_disagg_shard_map_matches_batched():
     store = _bs(k, v, C)
     q = jax.random.normal(jax.random.fold_in(KEY, 3), (B, H, D))
     cfg = MoSKAConfig(top_k_chunks=2)
-    with mesh:
+    with jax.set_mesh(mesh):
         o1, l1 = disaggregated_shared_attention(
             q, store.k[0], store.v[0], store.emb[0], cfg, mesh)
     r = route(q, store.emb[0], 2)
