@@ -12,25 +12,11 @@ from __future__ import annotations
 from typing import NamedTuple, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 
-from repro import obs
 from repro.configs.base import MoSKAConfig
 from repro.core import router as router_lib
 from repro.core import shared_attention as sa
 from repro.models import layers as L
-
-
-def _record_merge(lse_u: jax.Array, lse_s: jax.Array, phase: str) -> None:
-    """Mixture diagnostics: how much attention mass the routed shared path
-    contributes vs the request's unique cache (per-head win fraction).
-    jit-safe; no-op unless the engine enabled jit metrics."""
-    if not obs.metrics.JIT_METRICS:
-        return
-    obs.jit_inc(f"moska/{phase}/calls", 1)
-    obs.jit_observe(f"moska/{phase}/shared_win_frac",
-                    jnp.mean((lse_s > lse_u).astype(jnp.float32)),
-                    edges=obs.FRACTION_EDGES)
 
 
 class MoskaLayerContext(NamedTuple):
@@ -55,22 +41,23 @@ def moska_decode_attention(
     *,
     window: int = 0,
     kernel: Optional[str] = None,
-    layer_idx: Optional[jax.Array] = None,
-) -> jax.Array:
-    """Returns merged attention output (B, H, D)."""
-    o_u, lse_u = L.decode_attention(q, k_cache, v_cache, kv_len,
-                                    window=window, return_lse=True)
+) -> Tuple[jax.Array, Optional[sa.DispatchStats]]:
+    """Returns merged attention output (B, H, D) and the shared path's
+    dispatch stats (None without a store)."""
+    with jax.named_scope("unique_attn"):
+        o_u, lse_u = L.decode_attention(q, k_cache, v_cache, kv_len,
+                                        window=window, return_lse=True)
     if ctx is None or not cfg.enabled:
-        return o_u
-    part = sa.shared_attention_batched(
-        q[:, None], ctx.k, ctx.v, ctx.routing,
-        capacity_factor=cfg.query_capacity_factor, kernel=kernel,
-        layer_idx=layer_idx)
+        return o_u, None
+    with jax.named_scope("shared_dispatch_gemm"):
+        part = sa.shared_attention_batched(
+            q[:, None], ctx.k, ctx.v, ctx.routing,
+            capacity_factor=cfg.query_capacity_factor, kernel=kernel)
     o_s = part.out[:, 0]                 # (B, H, D)
     lse_s = part.lse[:, 0]               # (B, H)
-    _record_merge(lse_u, lse_s, "decode")
-    out, _ = L.merge_partial_attention([o_u, o_s], [lse_u, lse_s])
-    return out
+    with jax.named_scope("lse_merge"):
+        out, _ = L.merge_partial_attention([o_u, o_s], [lse_u, lse_s])
+    return out, part.stats
 
 
 def moska_prefill_attention(
@@ -84,25 +71,26 @@ def moska_prefill_attention(
     window: int = 0,
     route_block: int = 128,
     kernel: Optional[str] = None,
-    layer_idx: Optional[jax.Array] = None,
-) -> jax.Array:
+) -> Tuple[jax.Array, Optional[sa.DispatchStats]]:
     """Prefill: causal attention over the unique prefix, plus routed shared
-    attention for every query block when a shared corpus is attached."""
-    o_u, lse_u = L.flash_attention(q, k, v, causal=True, q_offset=q_offset,
-                                   kv_offset=q_offset, window=window,
-                                   return_lse=True)
+    attention for every query block when a shared corpus is attached.
+    Returns the output and the dispatch stats (None without a store)."""
+    with jax.named_scope("unique_attn"):
+        o_u, lse_u = L.flash_attention(q, k, v, causal=True,
+                                       q_offset=q_offset, kv_offset=q_offset,
+                                       window=window, return_lse=True)
     if ctx is None or not cfg.enabled:
-        return o_u
+        return o_u, None
     B, S, H, D = q.shape
     nb = S // route_block
     # (B*nb) groups of route_block queries
     qg = q.reshape(B * nb, route_block, H, D)
-    part = sa.shared_attention_batched(
-        qg, ctx.k, ctx.v, ctx.routing,
-        capacity_factor=cfg.query_capacity_factor, kernel=kernel,
-        layer_idx=layer_idx)
+    with jax.named_scope("shared_dispatch_gemm"):
+        part = sa.shared_attention_batched(
+            qg, ctx.k, ctx.v, ctx.routing,
+            capacity_factor=cfg.query_capacity_factor, kernel=kernel)
     o_s = part.out.reshape(B, S, H, D)
     lse_s = part.lse.reshape(B, S, H)
-    _record_merge(lse_u, lse_s, "prefill")
-    out, _ = L.merge_partial_attention([o_u, o_s], [lse_u, lse_s])
-    return out
+    with jax.named_scope("lse_merge"):
+        out, _ = L.merge_partial_attention([o_u, o_s], [lse_u, lse_s])
+    return out, part.stats

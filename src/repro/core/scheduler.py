@@ -44,6 +44,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import itertools
+import time
 from dataclasses import dataclass, field
 from typing import Callable, Deque, Dict, List, Optional, Sequence
 
@@ -57,7 +58,12 @@ class Request:
     prompt: List[int]
     max_new_tokens: int
     corpus_id: Optional[str] = None      # shared KV store this request uses
+    # lifecycle stamps, on the host's time.perf_counter() clock: submitted,
+    # given a slot, first token recorded, released
     arrival: float = 0.0
+    admitted_at: Optional[float] = None
+    first_token_at: Optional[float] = None
+    finished_at: Optional[float] = None
     # lifecycle
     generated: List[int] = field(default_factory=list)
     slot: int = -1
@@ -135,7 +141,7 @@ class Scheduler:
                 "up to the block budget")
         uid = next(self._uid)
         self.queue.append(Request(uid, list(prompt), max_new_tokens,
-                                  corpus_id))
+                                  corpus_id, arrival=time.perf_counter()))
         return uid
 
     # -- memory accounting ---------------------------------------------
@@ -264,6 +270,10 @@ class Scheduler:
                 break
             if offloaded > 0:
                 obs.get_registry().inc("scheduler/offload_admissions")
+            req.admitted_at = time.perf_counter()
+            obs.get_registry().observe("scheduler/admit_wait_s",
+                                       req.admitted_at - req.arrival,
+                                       obs.LATENCY_EDGES_S)
             req.slot = i
             self.slots[i] = req
             admitted.append(req)
@@ -389,7 +399,10 @@ class Scheduler:
 
     def record_token(self, req: Request, token: int, eos_id: int = -1):
         req.generated.append(token)
+        if req.first_token_at is None:
+            req.first_token_at = time.perf_counter()
         if req.remaining <= 0 or token == eos_id:
+            req.finished_at = time.perf_counter()
             req.done = True
             self.finished.append(req)
             self.slots[req.slot] = None
@@ -411,3 +424,18 @@ def wave_stats(reqs: List[Request]) -> Dict[str, float]:
         "distinct_corpora": len(by_corpus),
         "max_corpus_batch": max(by_corpus.values()) if by_corpus else 0,
     }
+
+
+def latency_stats(reqs: List[Request]) -> Dict[str, float]:
+    """Time to first token (p50, p95) and to the last token (p95) of the
+    finished requests, from their lifecycle stamps, in seconds."""
+    done = [r for r in reqs if r.finished_at is not None]
+
+    def pct(xs: List[float], q: float) -> float:
+        xs = sorted(xs)
+        return xs[min(len(xs) - 1, int(q * len(xs)))] if xs else 0.0
+
+    ttft = [r.first_token_at - r.arrival for r in done]
+    return {"ttft_p50_s": pct(ttft, 0.5), "ttft_p95_s": pct(ttft, 0.95),
+            "latency_p95_s": pct([r.finished_at - r.arrival for r in done],
+                                 0.95)}

@@ -27,7 +27,6 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import obs
 from repro.core import router as router_lib
 from repro.core.shared_kv import SharedKVStore
 from repro.sharding import lsc
@@ -35,38 +34,20 @@ from repro.sharding import lsc
 NEG_INF = -1e30
 
 
-def _record_dispatch(qmask: jax.Array, keep: jax.Array,
-                     layer_idx: Optional[jax.Array] = None) -> None:
-    """Dispatch-density metrics (paper's compute-bound claim hinges on
-    these): fraction of (chunk, capacity) slots filled, and how many
-    (group, k) routes fell off the capacity cliff. Runs inside the jit'd
-    decode step, so it goes through the trace-time-gated obs callbacks —
-    a no-op unless the serving engine enabled jit metrics.
-
-    ``layer_idx`` (traced scalar, from the layer scan) additionally files
-    the utilization under a per-layer histogram
-    (``moska/dispatch_capacity_utilization_by_layer/L{i}``) and the
-    capacity-cliff drops under a per-layer counter
-    (``moska/dropped_queries_by_layer/L{i}``), so routing hot spots —
-    and the layers actually losing routes to overflow — are attributable
-    individually."""
-    if not obs.metrics.JIT_METRICS:
-        return
-    util = jnp.mean(qmask.astype(jnp.float32))
-    dropped = jnp.sum(~keep)
-    obs.jit_observe("moska/dispatch_capacity_utilization", util,
-                    edges=obs.FRACTION_EDGES)
-    if layer_idx is not None:
-        obs.jit_observe_per("moska/dispatch_capacity_utilization_by_layer",
-                            layer_idx, util, edges=obs.FRACTION_EDGES)
-        obs.jit_inc_per("moska/dropped_queries_by_layer", layer_idx, dropped)
-    obs.jit_inc("moska/dispatched_queries", jnp.sum(keep))
-    obs.jit_inc("moska/dropped_queries", dropped)
+class DispatchStats(NamedTuple):
+    """Dispatch density of one routed call (the paper's compute-bound claim
+    hinges on these), returned as step outputs so the engine records them
+    on the host with the tokens: no host callback in the program. Scalars
+    per call; the layer scans stack them to ``(L,)``."""
+    fill: jax.Array        # f32: share of the (chunk, capacity) slots used
+    dispatched: jax.Array  # i32: (group, k) routes given a slot
+    dropped: jax.Array     # i32: routes that fell off the capacity cliff
 
 
 class SharedPartial(NamedTuple):
     out: jax.Array     # (G, Q, H, D)
     lse: jax.Array     # (G, Q, H) fp32; -inf where nothing attended
+    stats: Optional[DispatchStats] = None   # batched path only
 
 
 # ---------------------------------------------------------------------------
@@ -116,7 +97,6 @@ def shared_attention_batched(
     capacity_factor: float = 2.0,
     kernel: Optional[str] = None,  # None|'jnp'|'pallas'
     block_c: Optional[int] = None,  # kv-tile size for the pallas kernel
-    layer_idx: Optional[jax.Array] = None,  # for per-layer dispatch metrics
 ) -> SharedPartial:
     """Batched Shared KV Attention over routed chunks."""
     G, Q, H, D = q.shape
@@ -135,7 +115,10 @@ def shared_attention_batched(
     qd = lsc(qd, "chunks", None, None, "heads", None)
     qmask = jnp.zeros((E, capacity), bool).at[flat, drop_pos].set(
         keep, mode="drop")
-    _record_dispatch(qmask, keep, layer_idx)
+    # each kept route holds one distinct (chunk, slot): qmask has `sent`
+    # set entries, so its mean is sent / (E * capacity)
+    sent = jnp.sum(keep, dtype=jnp.int32)
+    stats = DispatchStats(sent / (E * capacity), sent, G * K - sent)
 
     if kernel == "pallas":
         from repro.kernels.shared_chunk_attn import shared_chunk_attention
@@ -171,7 +154,7 @@ def shared_attention_batched(
     out = out / jnp.maximum(denom, 1e-37)[..., None]
     lse = m + jnp.log(jnp.maximum(denom, 1e-37))
     lse = jnp.where(denom > 0, lse, NEG_INF)
-    return SharedPartial(out.astype(q.dtype), lse)
+    return SharedPartial(out.astype(q.dtype), lse, stats)
 
 
 # ---------------------------------------------------------------------------

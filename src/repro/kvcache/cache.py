@@ -108,12 +108,14 @@ def append_token(k_layer: jax.Array, v_layer: jax.Array, new_k: jax.Array,
                  ) -> tuple[jax.Array, jax.Array]:
     """Append one token per request at its current length.
 
-    k_layer: (B, S, KH, D); new_k: (B, KH, D); lengths: (B,).
+    k_layer: (B, S, KH, D); new_k: (B, KH, D); lengths: (B,). A length
+    outside the buffer writes its last row, as ``dynamic_update_slice``
+    clamps. One scatter over (slot, position) pairs: a ``vmap`` of
+    ``dynamic_update_slice`` compiles for the TPU into a loop over the B
+    slots, nine device ops a slot, for K and again for V in every layer.
     """
-    def upd(cache_b, new_b, len_b):
-        return jax.lax.dynamic_update_slice_in_dim(
-            cache_b, new_b[None].astype(cache_b.dtype), len_b, axis=0)
-
-    k_layer = jax.vmap(upd)(k_layer, new_k, lengths)
-    v_layer = jax.vmap(upd)(v_layer, new_v, lengths)
+    B, S = k_layer.shape[:2]
+    slot, at = jnp.arange(B), jnp.clip(lengths, 0, S - 1)
+    k_layer = k_layer.at[slot, at].set(new_k.astype(k_layer.dtype))
+    v_layer = v_layer.at[slot, at].set(new_v.astype(v_layer.dtype))
     return k_layer, v_layer

@@ -29,7 +29,7 @@ import numpy as np
 
 from repro import obs
 from repro.configs import get_config
-from repro.core.scheduler import wave_stats
+from repro.core.scheduler import latency_stats, wave_stats
 from repro.data.pipeline import CorpusSpec, synthesize_corpus
 from repro.launch.compile_cache import init_compile_cache
 from repro.models.model import build_model
@@ -188,6 +188,10 @@ def main(argv=None) -> dict:
         "hbm_high_water_bytes":
             reg.gauge("engine/hbm_high_water_bytes").value,
         "wave": wave_stats(done),
+        "requests": latency_stats(done),
+        "admit_wait_p95_s": reg.histogram(
+            "scheduler/admit_wait_s", obs.LATENCY_EDGES_S).quantile(0.95),
+        "backend_compiles": int(reg.counter("jax/backend_compiles").value),
     }
     if args.kv_layout == "paged":
         summary["host_pool_blocks"] = host_pool_blocks

@@ -80,6 +80,13 @@ def unembed_matrix(cfg: ModelConfig, params: Params) -> jax.Array:
     return params["unembed"]["unembed"]
 
 
+def _lm_head(cfg: ModelConfig, params: Params, x: jax.Array) -> jax.Array:
+    """x: (B, d) last hidden states -> (B, V) float32 logits."""
+    with jax.named_scope("lm_head"):
+        return jnp.einsum("bd,vd->bv", x, unembed_matrix(cfg, params),
+                          preferred_element_type=jnp.float32)
+
+
 # ---------------------------------------------------------------------------
 # layer bodies
 # ---------------------------------------------------------------------------
@@ -132,9 +139,9 @@ def _layer_prefill(cfg: ModelConfig, x: jax.Array, lp: Params,
                    shared: Optional[Tuple[jax.Array, jax.Array, jax.Array]],
                    q_offset: jax.Array,
                    true_len: Optional[jax.Array] = None,
-                   layer_idx: Optional[jax.Array] = None,
                    kernel: Optional[str] = None
-                   ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+                   ) -> Tuple[jax.Array, jax.Array, jax.Array,
+                              Optional[sa.DispatchStats]]:
     """Prefill layer: causal attention + cache write + optional MoSKA path.
 
     ``true_len`` (traced scalar ok): the real prompt length when the
@@ -143,7 +150,7 @@ def _layer_prefill(cfg: ModelConfig, x: jax.Array, lp: Params,
     matches the exact-length program; pad rows themselves produce garbage
     that the caller discards.
 
-    Returns (x_out, new_k_layer, new_v_layer, aux).
+    Returns (x_out, new_k_layer, new_v_layer, dispatch stats or None).
     """
     h = L.rms_norm(x, lp["ln1"]["scale"], cfg.rms_eps)
     q, k, v = L.qkv_project(h, lp["attn"], cfg.num_heads, cfg.num_kv_heads,
@@ -153,47 +160,50 @@ def _layer_prefill(cfg: ModelConfig, x: jax.Array, lp: Params,
     q = lsc(q, "batch", "seq", "heads", None)
     kc, vc = write_prefix(kc, vc, k, v)
 
-    ctx = None
+    stats = None
     if shared is not None and cfg.moska.enabled:
         sk, sv, semb = _shared_layer(shared, x.dtype)
         B, S, H, D = q.shape
         rb = min(128, S)
         nb = S // rb
-        if true_len is None:
-            pooled = jnp.mean(q.reshape(B * nb, rb, H, D), axis=1)
-        else:
-            valid = (jnp.arange(S) < true_len).astype(q.dtype)     # (S,)
-            qs = (q * valid[None, :, None, None]).reshape(B, nb, rb, H, D)
-            cnt = jnp.maximum(valid.reshape(nb, rb).sum(axis=1), 1.0)
-            pooled = (jnp.sum(qs, axis=2) /
-                      cnt[None, :, None, None]).reshape(B * nb, H, D)
-        routing = router_lib.route(pooled, semb, cfg.moska.top_k_chunks)
+        with jax.named_scope("shared_route"):
+            if true_len is None:
+                pooled = jnp.mean(q.reshape(B * nb, rb, H, D), axis=1)
+            else:
+                valid = (jnp.arange(S) < true_len).astype(q.dtype)  # (S,)
+                qs = (q * valid[None, :, None, None]).reshape(B, nb, rb, H,
+                                                              D)
+                cnt = jnp.maximum(valid.reshape(nb, rb).sum(axis=1), 1.0)
+                pooled = (jnp.sum(qs, axis=2) /
+                          cnt[None, :, None, None]).reshape(B * nb, H, D)
+            routing = router_lib.route(pooled, semb, cfg.moska.top_k_chunks)
         ctx = MA.MoskaLayerContext(sk, sv, routing)
-        o = MA.moska_prefill_attention(
+        o, stats = MA.moska_prefill_attention(
             q, k, v, ctx, cfg.moska, q_offset=q_offset,
-            window=cfg.attn_window, route_block=rb, kernel=kernel,
-            layer_idx=layer_idx)
+            window=cfg.attn_window, route_block=rb, kernel=kernel)
     else:
-        o = L.flash_attention(q, k, v, causal=True, q_offset=q_offset,
-                              kv_offset=q_offset, window=cfg.attn_window)
+        with jax.named_scope("unique_attn"):
+            o = L.flash_attention(q, k, v, causal=True, q_offset=q_offset,
+                                  kv_offset=q_offset, window=cfg.attn_window)
     x = x + lsc(_attn_out_proj(o, lp), "batch", "seq", None)
     h2 = L.rms_norm(x, lp["ln2"]["scale"], cfg.rms_eps)
-    y, aux = _ffn(cfg, lp, h2)
+    with jax.named_scope("mlp"):
+        y, _ = _ffn(cfg, lp, h2)
     x = x + lsc(y, "batch", "seq", None)
-    return x, kc, vc, aux
+    return x, kc, vc, stats
 
 
 def _layer_decode(cfg: ModelConfig, x: jax.Array, lp: Params,
                   positions: jax.Array,
                   kc: jax.Array, vc: jax.Array, lengths: jax.Array,
                   shared: Optional[Tuple[jax.Array, jax.Array, jax.Array]],
-                  kernel: Optional[str] = None,
-                  layer_idx: Optional[jax.Array] = None
-                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+                  kernel: Optional[str] = None
+                  ) -> Tuple[jax.Array, jax.Array, jax.Array,
+                             Optional[sa.DispatchStats]]:
     """Decode layer: one token per request.
 
     x: (B, d); positions: (B,) absolute position of the new token.
-    Returns (x_out, new_k_layer, new_v_layer).
+    Returns (x_out, new_k_layer, new_v_layer, dispatch stats or None).
     """
     B, d = x.shape
     h = L.rms_norm(x, lp["ln1"]["scale"], cfg.rms_eps)
@@ -206,19 +216,28 @@ def _layer_decode(cfg: ModelConfig, x: jax.Array, lp: Params,
     kc, vc = append_token(kc, vc, k, v, lengths)
     new_len = lengths + 1
 
+    o, stats = _decode_mixture(cfg, x, q, kc, vc, new_len, shared, kernel)
+    x = x + _attn_out_proj(o, lp)
+    h2 = L.rms_norm(x, lp["ln2"]["scale"], cfg.rms_eps)
+    with jax.named_scope("mlp"):
+        y, _ = _ffn(cfg, lp, h2[:, None])
+    x = x + y[:, 0]
+    return x, kc, vc, stats
+
+
+def _decode_mixture(cfg: ModelConfig, x: jax.Array, q: jax.Array,
+                    kc: jax.Array, vc: jax.Array, new_len: jax.Array,
+                    shared, kernel: Optional[str]):
+    """The decode layer's attention: routes ``q`` over the layer's shared
+    chunks when a store is attached. Returns (output, stats or None)."""
     ctx = None
     if shared is not None and cfg.moska.enabled:
         sk, sv, semb = _shared_layer(shared, x.dtype)
-        routing = router_lib.route(q, semb, cfg.moska.top_k_chunks)
+        with jax.named_scope("shared_route"):
+            routing = router_lib.route(q, semb, cfg.moska.top_k_chunks)
         ctx = MA.MoskaLayerContext(sk, sv, routing)
-    o = MA.moska_decode_attention(q, kc, vc, new_len, ctx, cfg.moska,
-                                  window=cfg.attn_window, kernel=kernel,
-                                  layer_idx=layer_idx)
-    x = x + _attn_out_proj(o, lp)
-    h2 = L.rms_norm(x, lp["ln2"]["scale"], cfg.rms_eps)
-    y, _ = _ffn(cfg, lp, h2[:, None])
-    x = x + y[:, 0]
-    return x, kc, vc
+    return MA.moska_decode_attention(q, kc, vc, new_len, ctx, cfg.moska,
+                                     window=cfg.attn_window, kernel=kernel)
 
 
 def _layer_decode_paged(cfg: ModelConfig, x: jax.Array, lp: Params,
@@ -227,9 +246,9 @@ def _layer_decode_paged(cfg: ModelConfig, x: jax.Array, lp: Params,
                         table: jax.Array, lengths: jax.Array,
                         shared: Optional[Tuple[jax.Array, jax.Array,
                                                jax.Array]],
-                        kernel: Optional[str] = None,
-                        layer_idx: Optional[jax.Array] = None
-                        ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+                        kernel: Optional[str] = None
+                        ) -> Tuple[jax.Array, jax.Array, jax.Array,
+                                   Optional[sa.DispatchStats]]:
     """Paged decode layer: identical math to ``_layer_decode`` but the
     unique KV lives in a block pool.
 
@@ -255,19 +274,13 @@ def _layer_decode_paged(cfg: ModelConfig, x: jax.Array, lp: Params,
     kc = gather_layer(kp, table)                     # (B, M*bs, KH, D)
     vc = gather_layer(vp, table)
 
-    ctx = None
-    if shared is not None and cfg.moska.enabled:
-        sk, sv, semb = _shared_layer(shared, x.dtype)
-        routing = router_lib.route(q, semb, cfg.moska.top_k_chunks)
-        ctx = MA.MoskaLayerContext(sk, sv, routing)
-    o = MA.moska_decode_attention(q, kc, vc, new_len, ctx, cfg.moska,
-                                  window=cfg.attn_window, kernel=kernel,
-                                  layer_idx=layer_idx)
+    o, stats = _decode_mixture(cfg, x, q, kc, vc, new_len, shared, kernel)
     x = x + _attn_out_proj(o, lp)
     h2 = L.rms_norm(x, lp["ln2"]["scale"], cfg.rms_eps)
-    y, _ = _ffn(cfg, lp, h2[:, None])
+    with jax.named_scope("mlp"):
+        y, _ = _ffn(cfg, lp, h2[:, None])
     x = x + y[:, 0]
-    return x, kp, vp
+    return x, kp, vp, stats
 
 
 # ---------------------------------------------------------------------------
@@ -384,8 +397,10 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
             frontend_embeds: Optional[jax.Array] = None,
             start_pos: int = 0,
             true_len: Optional[jax.Array] = None,
-            kernel: Optional[str] = None) -> Tuple[jax.Array, KVCache]:
-    """Process the unique prefix; returns (last-token logits, filled cache).
+            kernel: Optional[str] = None, return_stats: bool = False):
+    """Process the unique prefix; returns (last-token logits, filled cache),
+    and with ``return_stats`` the store's per-layer ``DispatchStats`` (each
+    field ``(L,)``; None without a store) as a third element.
 
     ``true_len`` (traced scalar ok): real prompt length when ``tokens`` is
     right-padded to a prefill bucket — logits are taken at position
@@ -401,21 +416,15 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
     shared = _shared_xs(cfg, store)
 
     def scan_body(x, xs):
-        if shared is not None:
-            lp, kc, vc, li, sh = xs
-        else:
-            lp, kc, vc, li = xs
-            sh = None
-        x, kc, vc, _ = _layer_prefill(cfg, x, lp, positions, kc, vc, sh,
-                                      jnp.asarray(start_pos),
-                                      true_len=true_len, layer_idx=li,
-                                      kernel=kernel)
-        return x, (kc, vc)
+        lp, kc, vc, sh = xs if shared is not None else (*xs, None)
+        x, kc, vc, st = _layer_prefill(cfg, x, lp, positions, kc, vc, sh,
+                                       jnp.asarray(start_pos),
+                                       true_len=true_len, kernel=kernel)
+        return x, (kc, vc, st)
 
-    lidx = jnp.arange(cfg.num_layers)
-    xs = ((params["layers"], cache.k, cache.v, lidx) if shared is None else
-          (params["layers"], cache.k, cache.v, lidx, shared))
-    x, (k_new, v_new) = jax.lax.scan(scan_body, x, xs)
+    xs = ((params["layers"], cache.k, cache.v) if shared is None else
+          (params["layers"], cache.k, cache.v, shared))
+    x, (k_new, v_new, stats) = jax.lax.scan(scan_body, x, xs)
     x = L.rms_norm(x, params["final_norm"]["scale"], cfg.rms_eps)
     if true_len is None:
         x_last = x[:, -1]
@@ -424,18 +433,19 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
         n_valid = jnp.asarray(true_len, jnp.int32)
         x_last = jax.lax.dynamic_index_in_dim(x, n_valid - 1, axis=1,
                                               keepdims=False)
-    logits = jnp.einsum("bd,vd->bv", x_last, unembed_matrix(cfg, params),
-                        preferred_element_type=jnp.float32)
+    logits = _lm_head(cfg, params, x_last)
     lengths = jnp.full((B,), n_valid, jnp.int32)
     offsets = jnp.full((B,), start_pos, jnp.int32)
-    return logits, KVCache(k_new, v_new, lengths, offsets)
+    out = (logits, KVCache(k_new, v_new, lengths, offsets))
+    return (*out, stats) if return_stats else out
 
 
 def decode_step(cfg: ModelConfig, params: Params, tokens: jax.Array,
                 cache: KVCache, store: Optional[SharedKVStore] = None,
                 positions: Optional[jax.Array] = None,
-                kernel: Optional[str] = None) -> Tuple[jax.Array, KVCache]:
-    """One decode step. tokens: (B,). Returns (logits (B, V), new cache)."""
+                kernel: Optional[str] = None, return_stats: bool = False):
+    """One decode step. tokens: (B,). Returns (logits (B, V), new cache),
+    and with ``return_stats`` the per-layer ``DispatchStats`` (or None)."""
     x = params["embed"]["embed"][tokens]                     # (B, d)
     x = lsc(x, "batch", None)
     if positions is None:
@@ -443,38 +453,33 @@ def decode_step(cfg: ModelConfig, params: Params, tokens: jax.Array,
     shared = _shared_xs(cfg, store)
 
     def scan_body(x, xs):
-        if shared is not None:
-            lp, kc, vc, li, sh = xs
-        else:
-            lp, kc, vc, li = xs
-            sh = None
-        x, kc, vc = _layer_decode(cfg, x, lp, positions, kc, vc,
-                                  cache.length, sh, kernel=kernel,
-                                  layer_idx=li)
-        return x, (kc, vc)
+        lp, kc, vc, sh = xs if shared is not None else (*xs, None)
+        x, kc, vc, st = _layer_decode(cfg, x, lp, positions, kc, vc,
+                                      cache.length, sh, kernel=kernel)
+        return x, (kc, vc, st)
 
-    lidx = jnp.arange(cfg.num_layers)
-    xs = ((params["layers"], cache.k, cache.v, lidx) if shared is None else
-          (params["layers"], cache.k, cache.v, lidx, shared))
-    x, (k_new, v_new) = jax.lax.scan(scan_body, x, xs)
+    xs = ((params["layers"], cache.k, cache.v) if shared is None else
+          (params["layers"], cache.k, cache.v, shared))
+    x, (k_new, v_new, stats) = jax.lax.scan(scan_body, x, xs)
     x = L.rms_norm(x, params["final_norm"]["scale"], cfg.rms_eps)
-    logits = jnp.einsum("bd,vd->bv", x, unembed_matrix(cfg, params),
-                        preferred_element_type=jnp.float32)
-    return logits, KVCache(k_new, v_new, cache.length + 1, cache.offset)
+    logits = _lm_head(cfg, params, x)
+    out = (logits, KVCache(k_new, v_new, cache.length + 1, cache.offset))
+    return (*out, stats) if return_stats else out
 
 
 def decode_step_paged(cfg: ModelConfig, params: Params, tokens: jax.Array,
                       pool: PagedKVCache, table: jax.Array,
                       lengths: jax.Array, offsets: jax.Array,
                       store: Optional[SharedKVStore] = None,
-                      kernel: Optional[str] = None
-                      ) -> Tuple[jax.Array, PagedKVCache]:
+                      kernel: Optional[str] = None,
+                      return_stats: bool = False):
     """One decode step over the paged unique-KV pool.
 
     tokens: (B,); pool: physical pages (L, N, bs, KH, D); table: (B, M)
     int32 block tables; lengths/offsets: (B,) — the host-side mirror of the
     slotted cache's length/offset vectors (``SlotTables``). Returns
-    (logits (B, V), new pool). The caller advances lengths (``tick``).
+    (logits (B, V), new pool), plus the per-layer ``DispatchStats`` with
+    ``return_stats``. The caller advances lengths (``tick``).
     """
     x = params["embed"]["embed"][tokens]                     # (B, d)
     x = lsc(x, "batch", None)
@@ -482,24 +487,19 @@ def decode_step_paged(cfg: ModelConfig, params: Params, tokens: jax.Array,
     shared = _shared_xs(cfg, store)
 
     def scan_body(x, xs):
-        if shared is not None:
-            lp, kp, vp, li, sh = xs
-        else:
-            lp, kp, vp, li = xs
-            sh = None
-        x, kp, vp = _layer_decode_paged(cfg, x, lp, positions, kp, vp,
-                                        table, lengths, sh, kernel=kernel,
-                                        layer_idx=li)
-        return x, (kp, vp)
+        lp, kp, vp, sh = xs if shared is not None else (*xs, None)
+        x, kp, vp, st = _layer_decode_paged(cfg, x, lp, positions, kp, vp,
+                                            table, lengths, sh,
+                                            kernel=kernel)
+        return x, (kp, vp, st)
 
-    lidx = jnp.arange(cfg.num_layers)
-    xs = ((params["layers"], pool.k, pool.v, lidx) if shared is None else
-          (params["layers"], pool.k, pool.v, lidx, shared))
-    x, (k_new, v_new) = jax.lax.scan(scan_body, x, xs)
+    xs = ((params["layers"], pool.k, pool.v) if shared is None else
+          (params["layers"], pool.k, pool.v, shared))
+    x, (k_new, v_new, stats) = jax.lax.scan(scan_body, x, xs)
     x = L.rms_norm(x, params["final_norm"]["scale"], cfg.rms_eps)
-    logits = jnp.einsum("bd,vd->bv", x, unembed_matrix(cfg, params),
-                        preferred_element_type=jnp.float32)
-    return logits, PagedKVCache(k_new, v_new)
+    logits = _lm_head(cfg, params, x)
+    out = (logits, PagedKVCache(k_new, v_new))
+    return (*out, stats) if return_stats else out
 
 
 # ---------------------------------------------------------------------------
@@ -511,15 +511,16 @@ def _layer_prefill_chunk(cfg: ModelConfig, x: jax.Array, lp: Params,
                          kc: jax.Array, vc: jax.Array,
                          base: jax.Array, chunk_len: jax.Array,
                          shared, start_pos: jax.Array,
-                         layer_idx: Optional[jax.Array] = None,
                          kernel: Optional[str] = None
-                         ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+                         ) -> Tuple[jax.Array, jax.Array, jax.Array,
+                                    Optional[sa.DispatchStats]]:
     """One chunk of a long prompt against the growing context view.
 
     x: (B, C, d) chunk activations (right-padded; ``chunk_len`` real);
     kc/vc: (B, V, KH, D) scratch context holding ``base`` earlier tokens;
     the chunk's fresh keys are written at ``base`` and causal attention
     runs over the whole view with ``kv_len = base + chunk_len`` masking.
+    Returns (x_out, kc, vc, dispatch stats or None).
     """
     h = L.rms_norm(x, lp["ln1"]["scale"], cfg.rms_eps)
     q, k, v = L.qkv_project(h, lp["attn"], cfg.num_heads, cfg.num_kv_heads,
@@ -533,46 +534,54 @@ def _layer_prefill_chunk(cfg: ModelConfig, x: jax.Array, lp: Params,
                                              axis=1)
     kv_valid = base + chunk_len
 
+    stats = None
     if shared is not None and cfg.moska.enabled:
         sk, sv, semb = _shared_layer(shared, x.dtype)
         B, C, H, D = q.shape
         rb = min(128, C)
         nb = C // rb
-        valid = (jnp.arange(C) < chunk_len).astype(q.dtype)        # (C,)
-        qs = (q * valid[None, :, None, None]).reshape(B, nb, rb, H, D)
-        cnt = jnp.maximum(valid.reshape(nb, rb).sum(axis=1), 1.0)
-        pooled = (jnp.sum(qs, axis=2) /
-                  cnt[None, :, None, None]).reshape(B * nb, H, D)
-        routing = router_lib.route(pooled, semb, cfg.moska.top_k_chunks)
-        o_u, lse_u = L.flash_attention(
-            q, kc, vc, causal=True, q_offset=start_pos + base,
-            kv_offset=start_pos, kv_len=kv_valid, window=cfg.attn_window,
-            return_lse=True)
-        part = sa.shared_attention_batched(
-            q.reshape(B * nb, rb, H, D), sk, sv, routing,
-            capacity_factor=cfg.moska.query_capacity_factor, kernel=kernel,
-            layer_idx=layer_idx)
+        with jax.named_scope("shared_route"):
+            valid = (jnp.arange(C) < chunk_len).astype(q.dtype)    # (C,)
+            qs = (q * valid[None, :, None, None]).reshape(B, nb, rb, H, D)
+            cnt = jnp.maximum(valid.reshape(nb, rb).sum(axis=1), 1.0)
+            pooled = (jnp.sum(qs, axis=2) /
+                      cnt[None, :, None, None]).reshape(B * nb, H, D)
+            routing = router_lib.route(pooled, semb, cfg.moska.top_k_chunks)
+        with jax.named_scope("unique_attn"):
+            o_u, lse_u = L.flash_attention(
+                q, kc, vc, causal=True, q_offset=start_pos + base,
+                kv_offset=start_pos, kv_len=kv_valid, window=cfg.attn_window,
+                return_lse=True)
+        with jax.named_scope("shared_dispatch_gemm"):
+            part = sa.shared_attention_batched(
+                q.reshape(B * nb, rb, H, D), sk, sv, routing,
+                capacity_factor=cfg.moska.query_capacity_factor,
+                kernel=kernel)
         o_s = part.out.reshape(B, C, H, D)
         lse_s = part.lse.reshape(B, C, H)
-        o, _ = L.merge_partial_attention([o_u, o_s], [lse_u, lse_s])
+        with jax.named_scope("lse_merge"):
+            o, _ = L.merge_partial_attention([o_u, o_s], [lse_u, lse_s])
+        stats = part.stats
     else:
-        o = L.flash_attention(q, kc, vc, causal=True,
-                              q_offset=start_pos + base,
-                              kv_offset=start_pos, kv_len=kv_valid,
-                              window=cfg.attn_window)
+        with jax.named_scope("unique_attn"):
+            o = L.flash_attention(q, kc, vc, causal=True,
+                                  q_offset=start_pos + base,
+                                  kv_offset=start_pos, kv_len=kv_valid,
+                                  window=cfg.attn_window)
     x = x + lsc(_attn_out_proj(o, lp), "batch", "seq", None)
     h2 = L.rms_norm(x, lp["ln2"]["scale"], cfg.rms_eps)
-    y, _ = _ffn(cfg, lp, h2)
+    with jax.named_scope("mlp"):
+        y, _ = _ffn(cfg, lp, h2)
     x = x + lsc(y, "batch", "seq", None)
-    return x, kc, vc
+    return x, kc, vc, stats
 
 
 def prefill_chunk(cfg: ModelConfig, params: Params, tokens: jax.Array,
                   cache: KVCache, store: Optional[SharedKVStore] = None,
                   start_pos=0,
                   chunk_len: Optional[jax.Array] = None,
-                  kernel: Optional[str] = None
-                  ) -> Tuple[jax.Array, KVCache]:
+                  kernel: Optional[str] = None,
+                  return_stats: bool = False):
     """Process one chunk of a long prompt; call repeatedly to prefill
     prompts past the largest bucket with a bounded jit cache.
 
@@ -580,7 +589,8 @@ def prefill_chunk(cfg: ModelConfig, params: Params, tokens: jax.Array,
     is the number of real tokens in it. ``cache`` is the scratch context
     (L, B, V, KH, D) already holding ``cache.length`` earlier tokens.
     Returns (logits at the chunk's last real token, cache extended by
-    ``chunk_len``). One compiled program per (C, V) shape pair regardless
+    ``chunk_len``), plus the per-layer ``DispatchStats`` with
+    ``return_stats``. One compiled program per (C, V) shape pair regardless
     of prompt length; numerically equivalent to the single-shot prefill
     (allclose), not bitwise (different contraction shapes).
     """
@@ -595,25 +605,20 @@ def prefill_chunk(cfg: ModelConfig, params: Params, tokens: jax.Array,
     shared = _shared_xs(cfg, store)
 
     def scan_body(x, xs):
-        if shared is not None:
-            lp, kc, vc, li, sh = xs
-        else:
-            lp, kc, vc, li = xs
-            sh = None
-        x, kc, vc = _layer_prefill_chunk(cfg, x, lp, positions, kc, vc,
-                                         base, chunk_len, sh, start,
-                                         layer_idx=li, kernel=kernel)
-        return x, (kc, vc)
+        lp, kc, vc, sh = xs if shared is not None else (*xs, None)
+        x, kc, vc, st = _layer_prefill_chunk(cfg, x, lp, positions, kc, vc,
+                                             base, chunk_len, sh, start,
+                                             kernel=kernel)
+        return x, (kc, vc, st)
 
-    lidx = jnp.arange(cfg.num_layers)
-    xs = ((params["layers"], cache.k, cache.v, lidx) if shared is None else
-          (params["layers"], cache.k, cache.v, lidx, shared))
-    x, (k_new, v_new) = jax.lax.scan(scan_body, x, xs)
+    xs = ((params["layers"], cache.k, cache.v) if shared is None else
+          (params["layers"], cache.k, cache.v, shared))
+    x, (k_new, v_new, stats) = jax.lax.scan(scan_body, x, xs)
     x = L.rms_norm(x, params["final_norm"]["scale"], cfg.rms_eps)
     x_last = jax.lax.dynamic_index_in_dim(x, chunk_len - 1, axis=1,
                                           keepdims=False)
-    logits = jnp.einsum("bd,vd->bv", x_last, unembed_matrix(cfg, params),
-                        preferred_element_type=jnp.float32)
+    logits = _lm_head(cfg, params, x_last)
     lengths = (cache.length + chunk_len).astype(jnp.int32)
     offsets = jnp.full_like(cache.offset, start)
-    return logits, KVCache(k_new, v_new, lengths, offsets)
+    out = (logits, KVCache(k_new, v_new, lengths, offsets))
+    return (*out, stats) if return_stats else out

@@ -7,6 +7,10 @@ API used by training, serving, launch, and tests.
     cache = model.init_cache(batch_size, max_seq)
     logits, cache = model.prefill(params, tokens, cache, store=...)
     logits, cache = model.decode_step(params, tokens, cache, store=...)
+
+With ``return_stats=True`` the serving programs also return the shared
+path's per-layer ``DispatchStats`` (None without a store, and always None
+outside the dense family, whose shared path is the only one serving uses).
 """
 from __future__ import annotations
 
@@ -61,25 +65,28 @@ class Model:
 
     def prefill(self, params, tokens, cache, store=None,
                 frontend_embeds=None, start_pos: int = 0, true_len=None,
-                kernel: Optional[str] = None):
+                kernel: Optional[str] = None, return_stats: bool = False):
         # true_len: real prompt length for bucket-padded serving prefill;
         # kernel: shared-attention implementation (dense family only)
         kw = {} if true_len is None else {"true_len": true_len}
-        if self.cfg.family in (DENSE, VLM, MOE):
+        dense = self.cfg.family in (DENSE, VLM, MOE)
+        if dense:
             kw["kernel"] = kernel
+            kw["return_stats"] = return_stats
         if self.cfg.family in (VLM, AUDIO):
-            return self._impl.prefill(self.cfg, params, tokens, cache,
-                                      store=store,
-                                      frontend_embeds=frontend_embeds,
-                                      start_pos=start_pos, **kw)
-        return self._impl.prefill(self.cfg, params, tokens, cache,
-                                  store=store, start_pos=start_pos, **kw)
+            kw["frontend_embeds"] = frontend_embeds
+        out = self._impl.prefill(self.cfg, params, tokens, cache,
+                                 store=store, start_pos=start_pos, **kw)
+        return (*out, None) if return_stats and not dense else out
 
     def decode_step(self, params, tokens, cache, store=None, positions=None,
-                    kernel: Optional[str] = None):
-        return self._impl.decode_step(self.cfg, params, tokens, cache,
-                                      store=store, positions=positions,
-                                      kernel=kernel)
+                    kernel: Optional[str] = None, return_stats: bool = False):
+        dense = self.cfg.family in (DENSE, VLM, MOE)
+        kw = {"return_stats": return_stats} if dense else {}
+        out = self._impl.decode_step(self.cfg, params, tokens, cache,
+                                     store=store, positions=positions,
+                                     kernel=kernel, **kw)
+        return (*out, None) if return_stats and not dense else out
 
     # -- paged KV layout (dense-family only) ---------------------------
     def _require_paged(self, what: str):
@@ -98,19 +105,23 @@ class Model:
 
     def decode_step_paged(self, params, tokens, pool, table, lengths,
                           offsets, store=None,
-                          kernel: Optional[str] = None):
+                          kernel: Optional[str] = None,
+                          return_stats: bool = False):
         self._require_paged("decode_step_paged")
         return self._impl.decode_step_paged(self.cfg, params, tokens, pool,
                                             table, lengths, offsets,
-                                            store=store, kernel=kernel)
+                                            store=store, kernel=kernel,
+                                            return_stats=return_stats)
 
     def prefill_chunk(self, params, tokens, cache, store=None,
                       start_pos=0, chunk_len=None,
-                      kernel: Optional[str] = None):
+                      kernel: Optional[str] = None,
+                      return_stats: bool = False):
         self._require_paged("prefill_chunk")
         return self._impl.prefill_chunk(self.cfg, params, tokens, cache,
                                         store=store, start_pos=start_pos,
-                                        chunk_len=chunk_len, kernel=kernel)
+                                        chunk_len=chunk_len, kernel=kernel,
+                                        return_stats=return_stats)
 
 
 def build_model(cfg: ModelConfig) -> Model:

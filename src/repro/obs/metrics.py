@@ -2,14 +2,17 @@
 
 The serving path is a mix of plain Python (scheduler, engine loop) and
 jit-compiled JAX (the decode step, including the routed shared-attention
-dispatch). Plain Python code records directly on the registry; traced code
-must NOT — a direct record inside a jit'd function fires once at trace time
-and never again. For traced values use ``jit_inc``/``jit_observe``/
-``jit_gauge``, which lower to ``jax.debug.callback`` so the record happens
-on every *execution*. Those helpers are gated by ``enable_jit_metrics``
-(checked at trace time) so the default compiled programs carry no host
-callbacks — dry-runs, HLO cost analysis, and multi-device lowering see the
-exact same HLO as before this module existed.
+dispatch). Plain Python code records directly on the registry. Served
+programs return what they count (``DispatchStats``) beside their results,
+and the engine records it on the host: no served program carries a host
+callback. A direct record inside a jit'd function fires once at trace time
+and never again; for ad-hoc debugging of traced values ``jit_inc``/
+``jit_observe``/``jit_gauge`` lower to ``jax.debug.callback`` so the record
+happens on every *execution*, gated by ``enable_jit_metrics`` (checked at
+trace time, off by default). No served module calls them.
+
+``watch_compiles`` counts the backend compiles JAX runs
+(``jax/backend_compiles``, ``jax/backend_compile_s``).
 
 This module deliberately has no jax import at module level: the scheduler
 and exporters stay importable in dependency-free contexts.
@@ -17,10 +20,14 @@ and exporters stay importable in dependency-free contexts.
 from __future__ import annotations
 
 import bisect
+import collections
 import threading
-from typing import Dict, List, Optional, Sequence, Tuple, Union
+from typing import Deque, Dict, List, Optional, Sequence, Tuple, Union
 
 Number = Union[int, float]
+
+#: finished spans a registry keeps: the most recent, oldest dropped first
+MAX_SPANS = 10_000
 
 # ---------------------------------------------------------------------------
 # bucket-edge conventions (documented in README "Metrics & tracing")
@@ -151,12 +158,15 @@ Metric = Union[Counter, Gauge, Histogram]
 
 
 class MetricsRegistry:
-    """Named metrics + completed trace spans. Thread-safe get-or-create."""
+    """Named metrics + the most recent ``MAX_SPANS`` completed trace spans.
+    Thread-safe get-or-create."""
 
     def __init__(self):
         self._lock = threading.RLock()
         self._metrics: Dict[str, Metric] = {}
-        self.spans: List[object] = []     # trace.Span, appended by trace.py
+        # trace.Span, appended by trace.py; a ring, so a long-running
+        # server's per-wave spans hold bounded memory
+        self.spans: Deque[object] = collections.deque(maxlen=MAX_SPANS)
 
     # -- get-or-create ---------------------------------------------------
     def _get(self, name: str, cls, *args) -> Metric:
@@ -262,14 +272,6 @@ def _cb_observe(name, edges, v):
     get_registry().observe(name, float(v), edges)
 
 
-def _cb_observe_per(prefix, edges, label, v):
-    get_registry().observe(f"{prefix}/L{int(label)}", float(v), edges)
-
-
-def _cb_inc_per(prefix, label, v):
-    get_registry().inc(f"{prefix}/L{int(label)}", float(v))
-
-
 def _callback(fn, *values) -> None:
     import jax
     jax.debug.callback(fn, *values)
@@ -296,24 +298,50 @@ def jit_observe(name: str, value,
         _callback(functools.partial(_cb_observe, name, tuple(edges)), value)
 
 
-def jit_observe_per(prefix: str, label, value,
-                    edges: Sequence[Number] = DEFAULT_EDGES) -> None:
-    """Histogram observation under a runtime-labeled name
-    (``{prefix}/L{label}``). Metric names are static strings, but inside a
-    ``lax.scan`` over layers the layer index is a traced value — so the
-    label rides to the host as a callback operand and the name is formed
-    there. Used for the per-layer dispatch histograms."""
-    if JIT_METRICS:
-        import functools
-        _callback(functools.partial(_cb_observe_per, prefix, tuple(edges)),
-                  label, value)
+# ---------------------------------------------------------------------------
+# compile counting
+# ---------------------------------------------------------------------------
+
+#: JAX's monitoring event around each XLA backend compile. It also wraps a
+#: load from the persistent compilation cache, which JAX announces first
+#: with ``CACHE_HIT_EVENT`` on the same thread; such a load is no compile.
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+_compile_listener_installed = False
+_compile_tls = threading.local()
 
 
-def jit_inc_per(prefix: str, label, value) -> None:
-    """Counter increment under a runtime-labeled name
-    (``{prefix}/L{label}``) — the counter sibling of
-    :func:`jit_observe_per`, for per-layer counts recorded inside the
-    layer ``lax.scan`` (e.g. dropped queries by layer)."""
-    if JIT_METRICS:
-        import functools
-        _callback(functools.partial(_cb_inc_per, prefix), label, value)
+def _on_event(event: str, **_) -> None:
+    if event == CACHE_HIT_EVENT:
+        _compile_tls.cache_hit = True
+
+
+def _on_duration_event(event: str, duration_s: float, **_) -> None:
+    if event != BACKEND_COMPILE_EVENT:
+        return
+    if getattr(_compile_tls, "cache_hit", False):
+        _compile_tls.cache_hit = False
+        return
+    reg = get_registry()
+    reg.inc("jax/backend_compiles")
+    reg.inc("jax/backend_compile_s", duration_s)
+
+
+def watch_compiles() -> None:
+    """Count every backend compile of this process, persistent-cache loads
+    left out, into the active registry (``jax/backend_compiles``,
+    ``jax/backend_compile_s``). The listeners are installed once per
+    process; each call creates both counters in the active registry, so a
+    window with no compile reads 0."""
+    global _compile_listener_installed
+    with _global_lock:
+        if not _compile_listener_installed:
+            import jax
+            jax.monitoring.register_event_listener(_on_event)
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_duration_event)
+            _compile_listener_installed = True
+    reg = get_registry()
+    reg.counter("jax/backend_compiles")
+    reg.counter("jax/backend_compile_s")

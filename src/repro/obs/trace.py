@@ -1,11 +1,16 @@
 """Wall-clock trace spans with parent nesting.
 
-``span("engine.decode_step", wave=3)`` measures a wall-clock interval and
+``span("engine.decode_wait", wave=3)`` measures a wall-clock interval and
 records it — with its parent span and nesting depth — into the active
 :class:`~repro.obs.metrics.MetricsRegistry`. Spans are host-side only (they
 time Python control flow, not device execution); wrap the device sync point
 (``np.asarray`` / ``block_until_ready``) inside the span to capture device
 time. Nesting is tracked per thread.
+
+Each span is also a ``jax.profiler.TraceAnnotation`` of the same name, so
+under the JAX profiler the program's spans land in the same trace as the
+device's operations (a no-op when no trace is being recorded). JAX is
+imported on the first span, not with this module.
 """
 from __future__ import annotations
 
@@ -39,6 +44,19 @@ class Span:
 
 
 _tls = threading.local()
+_annotation = None      # jax.profiler.TraceAnnotation, once imported
+
+
+def _trace_annotation(name: str):
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:     # obs without JAX: spans time the host only
+            def TraceAnnotation(name):
+                return contextlib.nullcontext()
+        _annotation = TraceAnnotation
+    return _annotation(name)
 
 
 def _stack() -> list:
@@ -58,8 +76,10 @@ def span(name: str, registry: Optional[M.MetricsRegistry] = None,
          record_histogram: bool = True,
          **attrs: Union[int, float, str]) -> Iterator[Span]:
     """Context manager: times the block, appends the finished Span to the
-    registry, and (by default) also feeds ``span/<name>/duration_s`` into a
-    latency histogram so spans aggregate without post-processing."""
+    registry's ring of recent spans, and (by default) also feeds
+    ``span/<name>/duration_s`` into a latency histogram so spans aggregate
+    without post-processing. The block also runs under a profiler
+    annotation named ``name``."""
     reg = registry if registry is not None else M.get_registry()
     st = _stack()
     parent = st[-1].name if st else None
@@ -67,7 +87,8 @@ def span(name: str, registry: Optional[M.MetricsRegistry] = None,
               attrs=dict(attrs))
     st.append(sp)
     try:
-        yield sp
+        with _trace_annotation(name):
+            yield sp
     finally:
         sp.end_s = time.perf_counter()
         st.pop()

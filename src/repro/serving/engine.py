@@ -58,7 +58,8 @@ import collections
 import hashlib
 import time
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
+from typing import (Any, Dict, List, NamedTuple, Optional, Sequence, Tuple,
+                    Union)
 
 import jax
 import jax.numpy as jnp
@@ -67,6 +68,7 @@ import numpy as np
 from repro import obs
 from repro.configs.base import ModelConfig
 from repro.core.scheduler import Request, Scheduler, SchedulerConfig
+from repro.core.shared_attention import DispatchStats
 from repro.core.shared_kv import SharedKVStore, build_store
 from repro.kvcache.block_table import (SlotTables, blocks_for,
                                        validate_block_size)
@@ -132,6 +134,21 @@ def bucket_for(buckets: Optional[Tuple[int, ...]], n: int) -> int:
     return n
 
 
+class Stepped(NamedTuple):
+    """A served program's new state (cache, pool or prefill context) with
+    the shared path's per-layer dispatch stats beside it (None without a
+    store). The programs keep two outputs, (token(s), state), so a wrapper
+    of them sees the shape it always had; one that hands back a bare state
+    records no stats (``unstep``)."""
+    state: Any
+    stats: Optional[DispatchStats]
+
+
+def unstep(out) -> Tuple[Any, Optional[DispatchStats]]:
+    """(state, stats) of a served program's second output."""
+    return (out.state, out.stats) if isinstance(out, Stepped) else (out, None)
+
+
 @dataclass
 class EngineConfig:
     max_slots: int = 4
@@ -143,9 +160,6 @@ class EngineConfig:
     # kernel: compiled on a TPU, interpreted on the CPU backend)
     kernel: Optional[str] = None
     cache_dtype: Any = jnp.bfloat16
-    # record dispatch-density metrics from inside the jit'd decode step
-    # (trace-time switch; adds host callbacks to the compiled program)
-    jit_metrics: bool = True
     # donate the persistent batch cache into the jit'd decode step and the
     # per-slot admission write (zero-copy; off = functional copies)
     donate_cache: bool = True
@@ -211,8 +225,7 @@ class ServingEngine:
             kv_layout=engine_cfg.kv_layout,
             block_size=engine_cfg.block_size))
         self.scheduler.set_store_evictor(self._on_store_evicted)
-        if engine_cfg.jit_metrics:
-            obs.enable_jit_metrics(True)
+        obs.watch_compiles()
         donate = engine_cfg.donate_cache
         self._decode = jax.jit(self._decode_impl,
                                static_argnames=("use_store",),
@@ -378,25 +391,31 @@ class ServingEngine:
         return self.scheduler.submit(prompt, max_new_tokens, corpus_id)
 
     # ------------------------------------------------------------------
+    # The jit'd programs below return the shared path's per-layer
+    # DispatchStats beside their new state (``Stepped``), so the dispatch
+    # metrics reach the host with the tokens, in the same transfer, and no
+    # program carries a host callback.
     def _decode_impl(self, params, tokens, cache, store, use_store: bool):
-        logits, cache = self.model.decode_step(
+        logits, cache, stats = self.model.decode_step(
             params, tokens, cache, store=store if use_store else None,
-            kernel=self.ecfg.kernel)
+            kernel=self.ecfg.kernel, return_stats=True)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return nxt, cache
+        return nxt, Stepped(cache, stats)
 
     def _prefill_impl(self, params, tokens, true_len, start, store,
                       use_store: bool):
         """One request's (possibly bucket-padded) prefill into a fresh
-        1-batch cache sized to the bucket. Returns (first token, cache)."""
+        1-batch cache sized to the bucket. Returns (first token,
+        Stepped(cache, stats))."""
         slot_cache = self.model.init_cache(1, tokens.shape[1],
                                            self.ecfg.cache_dtype)
-        logits, slot_cache = self.model.prefill(
+        logits, slot_cache, stats = self.model.prefill(
             params, tokens, slot_cache,
             store=store if use_store else None,
-            start_pos=start, true_len=true_len, kernel=self.ecfg.kernel)
+            start_pos=start, true_len=true_len, kernel=self.ecfg.kernel,
+            return_stats=True)
         first = jnp.argmax(logits[0]).astype(jnp.int32)
-        return first, slot_cache
+        return first, Stepped(slot_cache, stats)
 
     def _write_slot_impl(self, cache, slot_cache, slot, true_len):
         return write_slot_prefix(cache, slot_cache, slot, true_len)
@@ -416,21 +435,24 @@ class ServingEngine:
 
     def _decode_paged_impl(self, params, tokens, pool, table, lengths,
                            offsets, store, use_store: bool):
-        logits, pool = self.model.decode_step_paged(
+        logits, pool, stats = self.model.decode_step_paged(
             params, tokens, pool, table, lengths, offsets,
-            store=store if use_store else None, kernel=self.ecfg.kernel)
+            store=store if use_store else None, kernel=self.ecfg.kernel,
+            return_stats=True)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return nxt, pool
+        return nxt, Stepped(pool, stats)
 
     def _prefill_chunk_impl(self, params, tokens, ctx, start, chunk_len,
                             store, use_store: bool):
         """One fixed-size chunk of a long prompt against the growing
-        scratch context ``ctx``; returns (last-real-token argmax, ctx)."""
-        logits, ctx = self.model.prefill_chunk(
+        scratch context ``ctx``; returns (last-real-token argmax,
+        Stepped(ctx, stats))."""
+        logits, ctx, stats = self.model.prefill_chunk(
             params, tokens, ctx, store=store if use_store else None,
-            start_pos=start, chunk_len=chunk_len, kernel=self.ecfg.kernel)
+            start_pos=start, chunk_len=chunk_len, kernel=self.ecfg.kernel,
+            return_stats=True)
         first = jnp.argmax(logits[0]).astype(jnp.int32)
-        return first, ctx
+        return first, Stepped(ctx, stats)
 
     def _write_blocks_impl(self, pool, block_ids, slot_k, slot_v, true_len):
         """Scatter a (possibly bucket-padded) 1-batch prefill cache into
@@ -500,21 +522,25 @@ class ServingEngine:
         slot_tokens = np.zeros((B,), np.int32)
 
         waves = 0
+        host_s = 0.0        # engine host time since the last token vector
         try:
             with obs.span("engine.run"):
                 while not self.scheduler.idle and waves < max_waves:
-                    admitted = self.scheduler.schedule()
+                    with obs.span("engine.schedule") as sp:
+                        admitted = self.scheduler.schedule()
+                    host_s += sp.duration_s
                     for req in admitted:
-                        tp = time.perf_counter()
-                        cache, first = self._prefill_slot(cache, req)
-                        reg.observe("engine/prefill_latency_s",
-                                    time.perf_counter() - tp,
-                                    obs.LATENCY_EDGES_S)
-                        slot_tokens[req.slot] = first
-                        self.scheduler.record_token(req, int(first),
-                                                    self.ecfg.eos_id)
-                        self.metrics["tokens_generated"] += 1
-                        reg.inc("engine/tokens_generated")
+                        with obs.span("engine.prefill", uid=req.uid):
+                            tp = time.perf_counter()
+                            cache, first = self._prefill_slot(cache, req)
+                            reg.observe("engine/prefill_latency_s",
+                                        time.perf_counter() - tp,
+                                        obs.LATENCY_EDGES_S)
+                            slot_tokens[req.slot] = first
+                            self.scheduler.record_token(req, int(first),
+                                                        self.ecfg.eos_id)
+                            self.metrics["tokens_generated"] += 1
+                            reg.inc("engine/tokens_generated")
                     active = self.scheduler.active()
                     if not active:
                         if not admitted and not self.scheduler.idle:
@@ -532,34 +558,46 @@ class ServingEngine:
                                 "+ resident shared stores)")
                         waves += 1
                         continue
-                    store = self._active_store()
-                    use_store = store is not None and self.cfg.moska.enabled
-                    self._note_hbm(cache_nbytes)
-                    # batch density: fraction of the static wave the decode
-                    # step spends on live requests (the N of the GEMM)
-                    reg.observe("engine/wave_batch_density",
-                                len(active) / B, obs.FRACTION_EDGES)
-                    reg.observe("engine/wave_active_slots", len(active),
-                                obs.COUNT_EDGES)
-                    td = time.perf_counter()
-                    nxt, cache = self._decode(self.params,
-                                              jnp.asarray(slot_tokens),
-                                              cache, store, use_store)
-                    nxt = np.asarray(nxt)  # device sync: latency includes it
-                    reg.observe("engine/decode_step_latency_s",
-                                time.perf_counter() - td,
+                    with obs.span("engine.decode_dispatch") as sp:
+                        store = self._active_store()
+                        use_store = (store is not None
+                                     and self.cfg.moska.enabled)
+                        self._note_hbm(cache_nbytes)
+                        # batch density: fraction of the static wave the
+                        # decode step spends on live requests (the GEMM's N)
+                        reg.observe("engine/wave_batch_density",
+                                    len(active) / B, obs.FRACTION_EDGES)
+                        reg.observe("engine/wave_active_slots", len(active),
+                                    obs.COUNT_EDGES)
+                        td = time.perf_counter()
+                        nxt, out = self._decode(
+                            self.params, jnp.asarray(slot_tokens), cache,
+                            store, use_store)
+                        cache, stats = unstep(out)
+                    reg.observe("engine/host_step_s", host_s + sp.duration_s,
                                 obs.LATENCY_EDGES_S)
-                    for req in list(active):
-                        tok = int(nxt[req.slot])
-                        slot_tokens[req.slot] = tok
-                        self.scheduler.record_token(req, tok, self.ecfg.eos_id)
-                        self.metrics["tokens_generated"] += 1
-                        reg.inc("engine/tokens_generated")
-                        reg.inc("engine/decoded_tokens")
-                    self.metrics["decode_steps"] += 1
-                    reg.inc("engine/decode_steps")
-                    for hook in self.wave_hooks:
-                        hook()
+                    with obs.span("engine.decode_wait"):
+                        # device sync: latency includes it
+                        nxt, stats = jax.device_get((nxt, stats))
+                        reg.observe("engine/decode_step_latency_s",
+                                    time.perf_counter() - td,
+                                    obs.LATENCY_EDGES_S)
+                    with obs.span("engine.record") as sp:
+                        for req in list(active):
+                            tok = int(nxt[req.slot])
+                            slot_tokens[req.slot] = tok
+                            self.scheduler.record_token(req, tok,
+                                                        self.ecfg.eos_id)
+                            self.metrics["tokens_generated"] += 1
+                            reg.inc("engine/tokens_generated")
+                            reg.inc("engine/decoded_tokens")
+                        record_dispatch(reg, stats)
+                        self.metrics["decode_steps"] += 1
+                        reg.inc("engine/decode_steps")
+                    host_s = sp.duration_s
+                    with obs.span("engine.wave_hooks"):
+                        for hook in self.wave_hooks:
+                            hook()
                     waves += 1
         finally:
             self._cache = cache
@@ -787,17 +825,22 @@ class ServingEngine:
                 self._prefill_keys.add(pkey)
                 reg.set_gauge("engine/prefill_compile_count",
                               len(self._prefill_keys))
-            first, slot_cache = self._prefill(
+            first, out = self._prefill(
                 self.params, jnp.asarray(padded),
                 jnp.asarray(true_len, jnp.int32),
                 jnp.asarray(start, jnp.int32), store, use_store)
+            slot_cache, stats = unstep(out)
+            stats = [stats]
         else:
-            first, slot_cache = self._prefill_chunked_prompt(
+            first, slot_cache, stats = self._prefill_chunked_prompt(
                 req, store, use_store, start)
         pool = self._write_blocks(pool, jnp.asarray(ids, jnp.int32),
                                   slot_cache.k, slot_cache.v,
                                   jnp.asarray(true_len, jnp.int32))
         self._tables.assign(req.slot, ids, true_len, start)
+        first, stats = jax.device_get((first, stats))
+        for st in stats:
+            record_dispatch(self.registry, st)
         self.metrics["prefills"] += 1
         reg.inc("engine/prefills")
         reg.inc("engine/prefill_tokens", true_len)
@@ -807,7 +850,8 @@ class ServingEngine:
                                 start: int):
         """Long-prompt prefill in ``prefill_chunk``-token pieces against a
         growing scratch context (one compiled program per (chunk, context)
-        shape pair, bounded regardless of prompt length)."""
+        shape pair, bounded regardless of prompt length). Returns (first
+        token, context, each chunk's dispatch stats)."""
         C = self.ecfg.prefill_chunk
         true_len = len(req.prompt)
         v_tot = blocks_for(true_len, C) * C
@@ -818,18 +862,20 @@ class ServingEngine:
             self._prefill_keys.add(pkey)
             self.registry.set_gauge("engine/prefill_compile_count",
                                     len(self._prefill_keys))
-        first = None
+        first, stats = None, []
         for s0 in range(0, true_len, C):
             clen = min(C, true_len - s0)
             chunk = np.zeros((1, C), np.int32)
             chunk[0, :clen] = req.prompt[s0:s0 + clen]
-            first, ctx = self._prefill_chunked(
+            first, out = self._prefill_chunked(
                 self.params, jnp.asarray(chunk), ctx,
                 jnp.asarray(start, jnp.int32), jnp.asarray(clen, jnp.int32),
                 store, use_store)
+            ctx, st = unstep(out)
+            stats.append(st)
             self.registry.inc("engine/prefill_chunks")
         self.registry.inc("engine/chunked_prefills")
-        return first, ctx
+        return first, ctx, stats
 
     def _prepare_wave_blocks(self, pool: PagedKVCache,
                              active: List[Request]) -> PagedKVCache:
@@ -923,6 +969,14 @@ class ServingEngine:
             if pf.issue(key):
                 reg.inc("kvcache/prefetch_issued")
 
+    def _wave_bookkeeping(self, active: List[Request]) -> None:
+        """The paged wave's host-side work on the page tables: advance the
+        lengths, pre-allocate next pages, issue prefetches, read gauges."""
+        self._tables.tick()
+        self._speculative_appends(active)
+        self._issue_prefetches()
+        self._record_block_gauges()
+
     def _release_slot_paged(self, req: Request, slot: int) -> None:
         """Free a finished request's pages; with prefix sharing on, its
         prompt pages (incl. the partial tail — later writers CoW it) are
@@ -955,27 +1009,31 @@ class ServingEngine:
         slot_tokens = np.zeros((B,), np.int32)
 
         waves = 0
+        host_s = 0.0        # engine host time since the last token vector
         try:
             with obs.span("engine.run"):
                 while not self.scheduler.idle and waves < max_waves:
-                    # the offload admission path may extract pages from
-                    # the live pool during schedule() (read-only)
-                    self._cur_pool = pool
-                    admitted = self.scheduler.schedule()
+                    with obs.span("engine.schedule") as sp:
+                        # the offload admission path may extract pages from
+                        # the live pool during schedule() (read-only)
+                        self._cur_pool = pool
+                        admitted = self.scheduler.schedule()
+                    host_s += sp.duration_s
                     for req in admitted:
-                        tp = time.perf_counter()
-                        slot = req.slot
-                        pool, first = self._prefill_slot_paged(pool, req)
-                        reg.observe("engine/prefill_latency_s",
-                                    time.perf_counter() - tp,
-                                    obs.LATENCY_EDGES_S)
-                        slot_tokens[slot] = first
-                        self.scheduler.record_token(req, int(first),
-                                                    self.ecfg.eos_id)
-                        if req.done:
-                            self._release_slot_paged(req, slot)
-                        self.metrics["tokens_generated"] += 1
-                        reg.inc("engine/tokens_generated")
+                        with obs.span("engine.prefill", uid=req.uid):
+                            tp = time.perf_counter()
+                            slot = req.slot
+                            pool, first = self._prefill_slot_paged(pool, req)
+                            reg.observe("engine/prefill_latency_s",
+                                        time.perf_counter() - tp,
+                                        obs.LATENCY_EDGES_S)
+                            slot_tokens[slot] = first
+                            self.scheduler.record_token(req, int(first),
+                                                        self.ecfg.eos_id)
+                            if req.done:
+                                self._release_slot_paged(req, slot)
+                            self.metrics["tokens_generated"] += 1
+                            reg.inc("engine/tokens_generated")
                     active = self.scheduler.active()
                     if not active:
                         if not admitted and not self.scheduler.idle:
@@ -991,68 +1049,75 @@ class ServingEngine:
                                 "bytes + resident shared stores)")
                         waves += 1
                         continue
-                    store = self._active_store()
-                    use_store = store is not None and self.cfg.moska.enabled
-                    pool = self._prepare_wave_blocks(pool, active)
-                    self._note_hbm(pool.nbytes)
-                    reg.observe("engine/wave_batch_density",
-                                len(active) / B, obs.FRACTION_EDGES)
-                    reg.observe("engine/wave_active_slots", len(active),
-                                obs.COUNT_EDGES)
-                    tbl, lens, offs = self._tables.device_args()
-                    td = time.perf_counter()
-                    nxt, pool = self._decode_paged(
-                        self.params, jnp.asarray(slot_tokens), pool,
-                        jnp.asarray(tbl), jnp.asarray(lens),
-                        jnp.asarray(offs), store, use_store)
+                    with obs.span("engine.decode_dispatch") as sp:
+                        store = self._active_store()
+                        use_store = (store is not None
+                                     and self.cfg.moska.enabled)
+                        pool = self._prepare_wave_blocks(pool, active)
+                        self._note_hbm(pool.nbytes)
+                        reg.observe("engine/wave_batch_density",
+                                    len(active) / B, obs.FRACTION_EDGES)
+                        reg.observe("engine/wave_active_slots", len(active),
+                                    obs.COUNT_EDGES)
+                        tbl, lens, offs = self._tables.device_args()
+                        td = time.perf_counter()
+                        nxt, out = self._decode_paged(
+                            self.params, jnp.asarray(slot_tokens), pool,
+                            jnp.asarray(tbl), jnp.asarray(lens),
+                            jnp.asarray(offs), store, use_store)
+                        pool, stats = unstep(out)
+                    reg.observe("engine/host_step_s", host_s + sp.duration_s,
+                                obs.LATENCY_EDGES_S)
                     # jax returns from _decode_paged as soon as the step is
-                    # *dispatched*; np.asarray(nxt) is the block. The wave's
+                    # *dispatched*; the device_get is the block. The wave's
                     # host-side bookkeeping (table tick, speculative page
                     # appends, prefetch issue, gauge reads) is identical
                     # either way — overlap mode runs it inside the dispatch
                     # window so the block absorbs it, sync mode runs it
-                    # after. None of it may touch the device pool: that
+                    # after, in a span of its own that counts as host step
+                    # time. None of it may touch the device pool: that
                     # buffer is donated into the in-flight step.
-                    if self.ecfg.overlap_waves:
-                        th = time.perf_counter()
-                        self._tables.tick()
-                        self._speculative_appends(active)
-                        self._issue_prefetches()
-                        self._record_block_gauges()
-                        reg.observe("engine/overlap_saved_s",
-                                    time.perf_counter() - th,
-                                    obs.LATENCY_EDGES_S)
+                    with obs.span("engine.decode_wait"):
+                        if self.ecfg.overlap_waves:
+                            th = time.perf_counter()
+                            self._wave_bookkeeping(active)
+                            reg.observe("engine/overlap_saved_s",
+                                        time.perf_counter() - th,
+                                        obs.LATENCY_EDGES_S)
                         ts = time.perf_counter()
-                        nxt = np.asarray(nxt)  # device sync (residual wait)
+                        # device sync (residual wait with overlap, else
+                        # the full wait)
+                        nxt, stats = jax.device_get((nxt, stats))
                         stall = time.perf_counter() - ts
-                    else:
-                        ts = time.perf_counter()
-                        nxt = np.asarray(nxt)  # device sync (full wait)
-                        stall = time.perf_counter() - ts
-                        self._tables.tick()
-                        self._speculative_appends(active)
-                        self._issue_prefetches()
-                        self._record_block_gauges()
+                    host_s = 0.0
+                    if not self.ecfg.overlap_waves:
+                        with obs.span("engine.wave_bookkeeping") as sp:
+                            self._wave_bookkeeping(active)
+                        host_s = sp.duration_s
                     reg.observe("engine/decode_step_latency_s",
                                 time.perf_counter() - td,
                                 obs.LATENCY_EDGES_S)
                     reg.observe("engine/decode_stall_s", stall,
                                 obs.LATENCY_EDGES_S)
-                    for req in list(active):
-                        tok = int(nxt[req.slot])
-                        slot = req.slot
-                        slot_tokens[slot] = tok
-                        self.scheduler.record_token(req, tok,
-                                                    self.ecfg.eos_id)
-                        if req.done:
-                            self._release_slot_paged(req, slot)
-                        self.metrics["tokens_generated"] += 1
-                        reg.inc("engine/tokens_generated")
-                        reg.inc("engine/decoded_tokens")
-                    self.metrics["decode_steps"] += 1
-                    reg.inc("engine/decode_steps")
-                    for hook in self.wave_hooks:
-                        hook()
+                    with obs.span("engine.record") as sp:
+                        for req in list(active):
+                            tok = int(nxt[req.slot])
+                            slot = req.slot
+                            slot_tokens[slot] = tok
+                            self.scheduler.record_token(req, tok,
+                                                        self.ecfg.eos_id)
+                            if req.done:
+                                self._release_slot_paged(req, slot)
+                            self.metrics["tokens_generated"] += 1
+                            reg.inc("engine/tokens_generated")
+                            reg.inc("engine/decoded_tokens")
+                        record_dispatch(reg, stats)
+                        self.metrics["decode_steps"] += 1
+                        reg.inc("engine/decode_steps")
+                    host_s += sp.duration_s
+                    with obs.span("engine.wave_hooks"):
+                        for hook in self.wave_hooks:
+                            hook()
                     waves += 1
         finally:
             self._pool = pool
@@ -1087,13 +1152,16 @@ class ServingEngine:
             self._prefill_keys.add(key)
             self.registry.set_gauge("engine/prefill_compile_count",
                                     len(self._prefill_keys))
-        first, slot_cache = self._prefill(
+        first, out = self._prefill(
             self.params, jnp.asarray(padded),
             jnp.asarray(true_len, jnp.int32), jnp.asarray(start, jnp.int32),
             store, use_store)
+        slot_cache, stats = unstep(out)
         cache = self._write_slot(cache, slot_cache,
                                  jnp.asarray(req.slot, jnp.int32),
                                  jnp.asarray(true_len, jnp.int32))
+        first, stats = jax.device_get((first, stats))
+        record_dispatch(self.registry, stats)
         self.metrics["prefills"] += 1
         self.registry.inc("engine/prefills")
         self.registry.inc("engine/prefill_tokens", true_len)
@@ -1112,6 +1180,24 @@ class ServingEngine:
         cache = self._write_slot_pytree(cache, slot_cache,
                                         jnp.asarray(req.slot, jnp.int32))
         return cache, first
+
+
+def record_dispatch(reg: obs.MetricsRegistry, stats) -> None:
+    """File one program call's per-layer dispatch stats (host copies,
+    fetched with the call's tokens) under the ``moska/*`` names: one
+    utilization observation per layer, the routes dispatched and dropped,
+    and the per-layer views. ``stats`` None (no store) records nothing."""
+    if stats is None:
+        return
+    for i, (fill, dropped) in enumerate(zip(stats.fill.tolist(),
+                                            stats.dropped.tolist())):
+        reg.observe("moska/dispatch_capacity_utilization", fill,
+                    obs.FRACTION_EDGES)
+        reg.observe(f"moska/dispatch_capacity_utilization_by_layer/L{i}",
+                    fill, obs.FRACTION_EDGES)
+        reg.inc(f"moska/dropped_queries_by_layer/L{i}", dropped)
+    reg.inc("moska/dispatched_queries", int(stats.dispatched.sum()))
+    reg.inc("moska/dropped_queries", int(stats.dropped.sum()))
 
 
 def _pytree_nbytes(tree) -> int:

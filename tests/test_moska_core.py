@@ -162,8 +162,8 @@ def _check_merge_exactness(G, K, seed):
     lens = jax.random.randint(jax.random.fold_in(key, 6), (G,), 1, S + 1)
     r = route(q, store.emb[0], E)   # full routing => exact
     ctx = MoskaLayerContext(store.k[0], store.v[0], r)
-    out = moska_decode_attention(q, kc, vc, lens, ctx,
-                                 MoSKAConfig(top_k_chunks=E))
+    out, _ = moska_decode_attention(q, kc, vc, lens, ctx,
+                                    MoSKAConfig(top_k_chunks=E))
     for g in range(G):
         keys = jnp.concatenate([_tokens_major(store.k[0]),
                                 kc[g, :lens[g]]], 0)

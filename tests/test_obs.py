@@ -2,8 +2,10 @@
 kernel edge cases it keeps honest.
 
 Covers: histogram bucketing (edges, overflow, quantiles), counter/gauge
-semantics, span nesting (parent/depth), exporter round-trip (JSON and line
-protocol), jit-safe recording through jax.debug.callback, and
+semantics, span nesting (parent/depth) and the span ring, exporter
+round-trip (JSON and line protocol), debug recording through
+jax.debug.callback, dispatch counts returned by the shared path, the
+backend-compile counter, and
 kernels/lse_merge.py on all-(-inf) LSE rows and merge associativity.
 """
 import json
@@ -175,70 +177,121 @@ def test_jit_metrics_disabled_is_noop(fresh_registry):
     assert reg.get("jit/calls") is None
 
 
-def test_dispatch_metrics_flow_from_shared_attention(fresh_registry):
-    """shared_attention_batched feeds the dispatch-density metrics the
-    serving engine exports."""
+def _routed_inputs(G=4, K=2, E=4, C=8, H=8, KH=2, D=16):
     from repro.core.router import Routing
-    from repro.core.shared_attention import shared_attention_batched
-    reg = fresh_registry
-    obs.enable_jit_metrics(True)
-    G, K, E, C, H, KH, D = 4, 2, 4, 8, 8, 2, 16
     key = jax.random.PRNGKey(0)
-    k = jax.random.normal(jax.random.fold_in(key, 1), (E, C, KH, D))
-    v = jax.random.normal(jax.random.fold_in(key, 2), (E, C, KH, D))
+    k = jax.random.normal(jax.random.fold_in(key, 1), (E, KH, C, D))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (E, KH, C, D))
     q = jax.random.normal(jax.random.fold_in(key, 3), (G, 1, H, D))
     ids = jnp.tile(jnp.arange(K, dtype=jnp.int32)[None], (G, 1))
-    r = Routing(ids, jnp.zeros((G, K)), jnp.zeros((G, E)))
-    jax.block_until_ready(
-        shared_attention_batched(q, k, v, r, capacity=G * K))
+    return q, k, v, Routing(ids, jnp.zeros((G, K)), jnp.zeros((G, E)))
+
+
+def test_dispatch_metrics_flow_from_shared_attention(fresh_registry):
+    """shared_attention_batched returns the dispatch counts the serving
+    engine exports, and the engine files them under the moska/* names."""
+    from repro.core.shared_attention import shared_attention_batched
+    from repro.serving.engine import record_dispatch
+    reg = fresh_registry
+    G, K, E = 4, 2, 4
+    q, k, v, r = _routed_inputs(G, K, E)
+    part = jax.jit(lambda q: shared_attention_batched(
+        q, k, v, r, capacity=G * K))(q)
+    st = jax.device_get(part.stats)
+    # every group routes to chunks 0 and 1: 2·G of E·G·K slots filled
+    assert float(st.fill) == pytest.approx(2 * G / (E * G * K))
+    assert (int(st.dispatched), int(st.dropped)) == (G * K, 0)
+    record_dispatch(reg, jax.tree.map(lambda x: np.asarray(x)[None], st))
     util = reg.get("moska/dispatch_capacity_utilization")
     assert util is not None and util.count == 1
     assert reg.counter("moska/dispatched_queries").value == G * K
     assert reg.counter("moska/dropped_queries").value == 0
 
 
-def test_jit_inc_per_labels_counters_by_traced_value(fresh_registry):
-    """jit_inc_per forms the metric name host-side from a traced label —
-    the per-layer counter mechanism (the label is a scan carry, not a
-    static string)."""
-    reg = fresh_registry
-    obs.enable_jit_metrics(True)
-
-    @jax.jit
-    def f(x):
-        def body(i, acc):
-            obs.jit_inc_per("t/drops_by_layer", i, i * 10)
-            return acc + i
-        return jax.lax.fori_loop(0, 3, body, x)
-
-    f(jnp.asarray(0)).block_until_ready()
-    assert reg.get("t/drops_by_layer/L0").value == 0
-    assert reg.get("t/drops_by_layer/L1").value == 10
-    assert reg.get("t/drops_by_layer/L2").value == 20
-    assert reg.get("t/drops_by_layer/L3") is None
-
-
 def test_per_layer_dispatch_metrics_from_shared_attention(fresh_registry):
-    """With layer_idx supplied, the dispatch path files utilization and
-    dropped-query counts under per-layer names as well as the totals."""
-    from repro.core.router import Routing
+    """Stats stacked by a layer scan come back ``(L,)``; the engine files
+    utilization and dropped-query counts under per-layer names as well as
+    the totals. Layer 1's capacity of 1 drops routes."""
     from repro.core.shared_attention import shared_attention_batched
+    from repro.serving.engine import record_dispatch
     reg = fresh_registry
-    obs.enable_jit_metrics(True)
-    G, K, E, C, H, KH, D = 4, 2, 4, 8, 8, 2, 16
-    key = jax.random.PRNGKey(0)
-    k = jax.random.normal(jax.random.fold_in(key, 1), (E, C, KH, D))
-    v = jax.random.normal(jax.random.fold_in(key, 2), (E, C, KH, D))
-    q = jax.random.normal(jax.random.fold_in(key, 3), (G, 1, H, D))
-    ids = jnp.tile(jnp.arange(K, dtype=jnp.int32)[None], (G, 1))
-    r = Routing(ids, jnp.zeros((G, K)), jnp.zeros((G, E)))
-    jax.block_until_ready(shared_attention_batched(
-        q, k, v, r, capacity=G * K, layer_idx=jnp.asarray(5)))
-    util = reg.get("moska/dispatch_capacity_utilization_by_layer/L5")
-    assert util is not None and util.count == 1
-    assert reg.counter("moska/dropped_queries_by_layer/L5").value == 0
-    # the totals still record alongside the per-layer views
-    assert reg.counter("moska/dispatched_queries").value == G * K
+    G, K = 4, 2
+    q, k, v, r = _routed_inputs(G, K)
+
+    def layer(cap):
+        return shared_attention_batched(q, k, v, r, capacity=cap).stats
+
+    st = jax.device_get([layer(G * K), layer(1), layer(G * K)])
+    stacked = jax.tree.map(lambda *xs: np.stack(xs), *st)
+    assert stacked.dropped.shape == (3,)
+    record_dispatch(reg, stacked)
+    for i in range(3):
+        util = reg.get(f"moska/dispatch_capacity_utilization_by_layer/L{i}")
+        assert util is not None and util.count == 1
+    drops = [reg.counter(f"moska/dropped_queries_by_layer/L{i}").value
+             for i in range(3)]
+    assert drops[0] == drops[2] == 0
+    # capacity 1 keeps one route per chunk: K chunks kept, the rest drop
+    assert drops[1] == G * K - K
+    assert reg.counter("moska/dropped_queries").value == sum(drops)
+    assert reg.counter("moska/dispatched_queries").value == \
+        3 * G * K - sum(drops)
+    assert reg.get("moska/dispatch_capacity_utilization").count == 3
+
+
+def test_span_ring_keeps_the_most_recent(fresh_registry):
+    reg = fresh_registry
+    n = obs.MAX_SPANS + 5
+    for i in range(n):
+        with obs.span("s", registry=reg, record_histogram=False, i=i):
+            pass
+    assert len(reg.spans) == obs.MAX_SPANS
+    assert reg.spans[0].attrs["i"] == 5
+    assert reg.spans[-1].attrs["i"] == n - 1
+    assert len(obs.to_dict(reg)["spans"]) == obs.MAX_SPANS
+
+
+def test_fresh_jit_counts_a_backend_compile(fresh_registry):
+    reg = fresh_registry
+    x = jnp.ones((3,))
+    obs.watch_compiles()
+    assert reg.counter("jax/backend_compiles").value == 0
+    c = float(np.random.default_rng().random())   # a program not compiled
+    jax.jit(lambda x: x * c + 1.0)(x).block_until_ready()
+    assert reg.counter("jax/backend_compiles").value == 1
+    assert reg.counter("jax/backend_compile_s").value > 0
+
+
+def test_persistent_cache_load_is_not_a_compile(fresh_registry, tmp_path):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    reg = fresh_registry
+    obs.watch_compiles()
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes",
+            "jax_enable_compilation_cache")
+    prev = {k: getattr(jax.config, k) for k in keys}
+    try:
+        jax.config.update("jax_enable_compilation_cache", True)
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        cc.reset_cache()
+
+        def f(x):
+            return jnp.sin(x) * 3.25 + 0.5
+
+        x = jnp.ones((5,))
+        n0 = reg.counter("jax/backend_compiles").value
+        jax.jit(f)(x).block_until_ready()
+        assert reg.counter("jax/backend_compiles").value == n0 + 1
+        jax.clear_caches()          # next call loads from the disk cache
+        jax.jit(f)(x).block_until_ready()
+        assert reg.counter("jax/backend_compiles").value == n0 + 1
+    finally:
+        for k, v in prev.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
 
 
 def test_streaming_exporter_flush_cadence(fresh_registry, tmp_path):

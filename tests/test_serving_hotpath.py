@@ -8,6 +8,8 @@ Differential guarantees:
     the same generations as exact-length prefill
   * a prompt-length sweep compiles at most one prefill program per bucket
   * per-slot writes never leak stale KV across slot reuse (dtypes, offsets)
+  * the decode append writes what a per-slot dynamic_update_slice writes,
+    clamped lengths included
   * corpus-B requests in a mixed-corpus stream decode against store B
     (regression: the scheduler used to mix corpora into one wave and the
     engine fed every slot the resident store)
@@ -20,8 +22,8 @@ import pytest
 from repro import obs
 from repro.configs import get_config
 from repro.data.pipeline import CorpusSpec, synthesize_corpus
-from repro.kvcache.cache import (KVCache, init_kv_cache, read_slot,
-                                 write_slot_prefix)
+from repro.kvcache.cache import (KVCache, append_token, init_kv_cache,
+                                 read_slot, write_slot_prefix)
 from repro.models.model import build_model
 from repro.serving.engine import (EngineConfig, ServingEngine, bucket_for,
                                   resolve_prefill_buckets)
@@ -123,6 +125,23 @@ def test_write_slot_prefix_no_stale_leak(dtype):
         np.testing.assert_array_equal(np.asarray(out.k[:, s]),
                                       np.asarray(stale.k[:, s]))
         assert int(out.length[s]) == S
+
+
+def test_append_token_equals_per_slot_update():
+    B, S, KH, D = 4, 6, 2, 3
+    rng = np.random.default_rng(0)
+    kc, vc = (jnp.asarray(rng.normal(size=(B, S, KH, D)), jnp.bfloat16)
+              for _ in range(2))
+    nk, nv = (jnp.asarray(rng.normal(size=(B, KH, D)), jnp.float32)
+              for _ in range(2))
+    lengths = jnp.array([0, 3, S - 1, S + 2], jnp.int32)   # last one clamps
+    got_k, got_v = append_token(kc, vc, nk, nv, lengths)
+    for b in range(B):
+        for c, n, got in ((kc, nk, got_k), (vc, nv, got_v)):
+            want = jax.lax.dynamic_update_slice_in_dim(
+                c[b], n[b][None].astype(c.dtype), lengths[b], axis=0)
+            np.testing.assert_array_equal(np.asarray(got[b], np.float32),
+                                          np.asarray(want, np.float32))
 
 
 def test_write_slot_prefix_matches_merge_reference():

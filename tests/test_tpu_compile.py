@@ -2,7 +2,8 @@
 attached: the chip's compiler refuses block layouts that interpret mode
 accepts (the last two dims of every block must be multiples of (8, 128) or
 equal the array's own), so these tests guard the kernels' layouts on a
-CPU-only host.
+CPU-only host. The decode step's KV append is checked the same way for the
+loop the chip's compiler makes of some scatters.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library,
@@ -19,6 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.lse_merge import lse_merge
+from repro.kvcache.cache import append_token
 from repro.kernels.router_score import router_scores
 from repro.kernels.shared_chunk_attn import shared_chunk_attention
 
@@ -74,3 +76,13 @@ def test_lse_merge_compiles(one_chip):
         functools.partial(lse_merge, interpret=False), one_chip,
         ((2, 256, 32, 64), jnp.bfloat16), ((2, 256, 32), jnp.float32))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_slot_append_compiles_to_a_scatter_without_a_loop(one_chip):
+    kv = ((32, 256, 4, 64), jnp.bfloat16)
+    new = ((32, 4, 64), jnp.bfloat16)
+    compiled = _compile(append_token, one_chip, kv, kv, new, new,
+                        ((32,), jnp.int32))
+    text = compiled.as_text()
+    assert "scatter(" in text
+    assert " while(" not in text
