@@ -32,7 +32,8 @@ def run(emit):
     ccache = init_kv_cache(cfg.num_layers, 1, E * C, cfg.num_kv_heads,
                            cfg.head_dim, jnp.float32)
     _, ccache = dense.prefill(cfg, params, corpus, ccache)
-    store = build_store(ccache.k[:, 0], ccache.v[:, 0], C)
+    store = build_store(ccache.k[:, 0], ccache.v[:, 0], C,
+                        head_dim=cfg.head_dim)
 
     # queries from a forward pass over fresh prompts (layer-0 q)
     B = 16

@@ -32,7 +32,8 @@ ctx = jax.random.randint(jax.random.fold_in(key, 1), (1, ctx_len), 0,
 ccache = init_kv_cache(cfg.num_layers, 1, ctx_len, cfg.num_kv_heads,
                        cfg.head_dim, jnp.float32)
 _, ccache = dense.prefill(cfg, params, ctx, ccache)
-store = build_store(ccache.k[:, 0], ccache.v[:, 0], cfg.moska.chunk_size)
+store = build_store(ccache.k[:, 0], ccache.v[:, 0], cfg.moska.chunk_size,
+                    head_dim=cfg.head_dim)
 print(f"context: {ctx_len} tokens as {store.num_chunks} chunks; "
       f"router reads top-{cfg.moska.top_k_chunks} per step "
       f"({100 * cfg.moska.top_k_chunks / store.num_chunks:.0f}% of context)")

@@ -30,7 +30,8 @@ corpus = jax.random.randint(jax.random.fold_in(key, 1), (1, corpus_len),
 ccache = init_kv_cache(cfg.num_layers, 1, corpus_len, cfg.num_kv_heads,
                        cfg.head_dim, jnp.float32)
 _, ccache = dense.prefill(cfg, params, corpus, ccache)
-store = build_store(ccache.k[:, 0], ccache.v[:, 0], cfg.moska.chunk_size)
+store = build_store(ccache.k[:, 0], ccache.v[:, 0], cfg.moska.chunk_size,
+                    head_dim=cfg.head_dim)
 print(f"shared store: {store.num_chunks} chunks x {store.chunk_size} tokens")
 
 # --- 2. concurrent requests decode against the shared store --------------

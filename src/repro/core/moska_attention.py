@@ -16,6 +16,7 @@ import jax
 from repro.configs.base import MoSKAConfig
 from repro.core import router as router_lib
 from repro.core import shared_attention as sa
+from repro.kernels.decode_attn import decode_attention
 from repro.models import layers as L
 
 
@@ -33,20 +34,23 @@ def route_layer(q_pooled: jax.Array, emb: jax.Array,
 
 def moska_decode_attention(
     q: jax.Array,                        # (B, H, D) one token per request
-    k_cache: jax.Array,                  # (B, S, KH, D) unique cache
+    k_cache: jax.Array,                  # (L, B, S, KH·D) unique cache
     v_cache: jax.Array,
     kv_len: jax.Array,                   # (B,)
     ctx: Optional[MoskaLayerContext],
     cfg: MoSKAConfig,
     *,
+    layer=0,                             # int32 scalar: the layer to read
     window: int = 0,
     kernel: Optional[str] = None,
 ) -> Tuple[jax.Array, Optional[sa.DispatchStats]]:
     """Returns merged attention output (B, H, D) and the shared path's
-    dispatch stats (None without a store)."""
+    dispatch stats (None without a store). The unique path is the Pallas
+    kernel ``kernels.decode_attn``, which reads layer ``layer`` of the
+    stacked cache in place; ``kernel`` picks the shared path's."""
     with jax.named_scope("unique_attn"):
-        o_u, lse_u = L.decode_attention(q, k_cache, v_cache, kv_len,
-                                        window=window, return_lse=True)
+        o_u, lse_u = decode_attention(q, k_cache, v_cache, kv_len, layer,
+                                      window=window)
     if ctx is None or not cfg.enabled:
         return o_u, None
     with jax.named_scope("shared_dispatch_gemm"):

@@ -93,14 +93,23 @@ def _quantize(x: jax.Array):
 
 def build_store(k: jax.Array, v: jax.Array, chunk_size: int,
                 start_position: int = 0,
-                quantize: bool = False) -> SharedKVStore:
-    """Chunk a (L, S, KH, D) corpus KV into a SharedKVStore.
+                quantize: bool = False,
+                head_dim: Optional[int] = None) -> SharedKVStore:
+    """Chunk a corpus KV into a SharedKVStore.
 
-    Keys are expected post-RoPE at absolute corpus positions
+    k/v: (L, S, KH, D), or the slotted cache's lane-dense (L, S, KH·D) with
+    ``head_dim`` = D, which is split into heads here, once. Keys are
+    expected post-RoPE at absolute corpus positions
     ``start_position + [0, S)``; S must be a multiple of chunk_size.
     ``quantize=True`` stores int8 KV + per-(token, head) f32 scales
     (capacity/bandwidth parity with the paper's FP8 assumption).
     """
+    if k.ndim == 3:
+        if head_dim is None:
+            raise ValueError("a lane-dense (L, S, KH·D) corpus KV needs "
+                             "head_dim to be split into heads")
+        k = k.reshape(k.shape[:2] + (-1, head_dim))
+        v = v.reshape(v.shape[:2] + (-1, head_dim))
     L, S, KH, D = k.shape
     if S % chunk_size:
         raise ValueError(f"corpus length {S} not a multiple of chunk_size "

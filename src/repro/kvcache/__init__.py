@@ -1,6 +1,6 @@
 from repro.kvcache.cache import (  # noqa: F401
-    KVCache, abstract_kv_cache, append_token, init_kv_cache, read_slot,
-    write_prefix, write_slot_prefix,
+    KVCache, abstract_kv_cache, append_token, append_token_stacked,
+    init_kv_cache, read_slot, write_prefix, write_slot_prefix,
 )
 from repro.kvcache.block_table import (  # noqa: F401
     NULL_BLOCK, SlotTables, blocks_for, validate_block_size,

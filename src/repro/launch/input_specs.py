@@ -88,9 +88,10 @@ def _abstract_params(model: Model, rules, mesh):
 # ---------------------------------------------------------------------------
 
 _CACHE_AXES: Dict[str, Tuple[Optional[str], ...]] = {
-    # dense KVCache fields; seq dim over model = flash-decoding KV split
-    "k": (None, "batch", "kv_seq", "kv_heads", None),
-    "v": (None, "batch", "kv_seq", "kv_heads", None),
+    # dense KVCache fields (L, B, S, KH·D); seq dim over model =
+    # flash-decoding KV split
+    "k": (None, "batch", "kv_seq", "kv_heads"),
+    "v": (None, "batch", "kv_seq", "kv_heads"),
     "length": ("batch",),
     "offset": ("batch",),
     # ssm

@@ -26,7 +26,8 @@ from repro.core import moska_attention as MA
 from repro.core import router as router_lib
 from repro.core import shared_attention as sa
 from repro.core.shared_kv import SharedKVStore
-from repro.kvcache.cache import KVCache, append_token, write_prefix
+from repro.kvcache.cache import (KVCache, append_token_stacked,
+                                 write_prefix)
 from repro.kvcache.paged import PagedKVCache, append_layer, gather_layer
 from repro.models import layers as L
 from repro.models import moe as moe_lib
@@ -195,40 +196,44 @@ def _layer_prefill(cfg: ModelConfig, x: jax.Array, lp: Params,
 
 def _layer_decode(cfg: ModelConfig, x: jax.Array, lp: Params,
                   positions: jax.Array,
-                  kc: jax.Array, vc: jax.Array, lengths: jax.Array,
+                  k: jax.Array, v: jax.Array, layer: jax.Array,
+                  lengths: jax.Array,
                   shared: Optional[Tuple[jax.Array, jax.Array, jax.Array]],
                   kernel: Optional[str] = None
                   ) -> Tuple[jax.Array, jax.Array, jax.Array,
                              Optional[sa.DispatchStats]]:
-    """Decode layer: one token per request.
+    """Decode layer ``layer``: one token per request.
 
-    x: (B, d); positions: (B,) absolute position of the new token.
-    Returns (x_out, new_k_layer, new_v_layer, dispatch stats or None).
+    x: (B, d); positions: (B,) absolute position of the new token; k/v:
+    the whole stacked cache (L, B, S, KH·D), written at one row per
+    request and read in place. Returns (x_out, k, v, dispatch stats or
+    None).
     """
     B, d = x.shape
     h = L.rms_norm(x, lp["ln1"]["scale"], cfg.rms_eps)
-    q, k, v = L.qkv_project(h[:, None], lp["attn"], cfg.num_heads,
-                            cfg.num_kv_heads, cfg.head_dim)
+    q, k_new, v_new = L.qkv_project(h[:, None], lp["attn"], cfg.num_heads,
+                                    cfg.num_kv_heads, cfg.head_dim)
     q = L.apply_rope(q, positions[:, None], cfg.rope_theta)[:, 0]  # (B,H,D)
-    k = L.apply_rope(k, positions[:, None], cfg.rope_theta)[:, 0]
-    v = v[:, 0]
+    k_new = L.apply_rope(k_new, positions[:, None], cfg.rope_theta)[:, 0]
     q = lsc(q, "batch", "heads", None)
-    kc, vc = append_token(kc, vc, k, v, lengths)
+    k, v = append_token_stacked(k, v, layer, k_new, v_new[:, 0], lengths)
     new_len = lengths + 1
 
-    o, stats = _decode_mixture(cfg, x, q, kc, vc, new_len, shared, kernel)
+    o, stats = _decode_mixture(cfg, x, q, k, v, layer, new_len, shared,
+                               kernel)
     x = x + _attn_out_proj(o, lp)
     h2 = L.rms_norm(x, lp["ln2"]["scale"], cfg.rms_eps)
     with jax.named_scope("mlp"):
         y, _ = _ffn(cfg, lp, h2[:, None])
     x = x + y[:, 0]
-    return x, kc, vc, stats
+    return x, k, v, stats
 
 
 def _decode_mixture(cfg: ModelConfig, x: jax.Array, q: jax.Array,
-                    kc: jax.Array, vc: jax.Array, new_len: jax.Array,
+                    k: jax.Array, v: jax.Array, layer, new_len: jax.Array,
                     shared, kernel: Optional[str]):
-    """The decode layer's attention: routes ``q`` over the layer's shared
+    """The decode layer's attention over layer ``layer`` of the stacked
+    cache ``k``/``v`` (L, B, S, KH·D); routes ``q`` over the layer's shared
     chunks when a store is attached. Returns (output, stats or None)."""
     ctx = None
     if shared is not None and cfg.moska.enabled:
@@ -236,8 +241,9 @@ def _decode_mixture(cfg: ModelConfig, x: jax.Array, q: jax.Array,
         with jax.named_scope("shared_route"):
             routing = router_lib.route(q, semb, cfg.moska.top_k_chunks)
         ctx = MA.MoskaLayerContext(sk, sv, routing)
-    return MA.moska_decode_attention(q, kc, vc, new_len, ctx, cfg.moska,
-                                     window=cfg.attn_window, kernel=kernel)
+    return MA.moska_decode_attention(q, k, v, new_len, ctx, cfg.moska,
+                                     layer=layer, window=cfg.attn_window,
+                                     kernel=kernel)
 
 
 def _layer_decode_paged(cfg: ModelConfig, x: jax.Array, lp: Params,
@@ -273,8 +279,12 @@ def _layer_decode_paged(cfg: ModelConfig, x: jax.Array, lp: Params,
     new_len = lengths + 1
     kc = gather_layer(kp, table)                     # (B, M*bs, KH, D)
     vc = gather_layer(vp, table)
+    # the slotted layout's lane-dense stack, of one layer
+    kc = kc.reshape(1, *kc.shape[:2], -1)
+    vc = vc.reshape(1, *vc.shape[:2], -1)
 
-    o, stats = _decode_mixture(cfg, x, q, kc, vc, new_len, shared, kernel)
+    o, stats = _decode_mixture(cfg, x, q, kc, vc, 0, new_len, shared,
+                               kernel)
     x = x + _attn_out_proj(o, lp)
     h2 = L.rms_norm(x, lp["ln2"]["scale"], cfg.rms_eps)
     with jax.named_scope("mlp"):
@@ -452,15 +462,21 @@ def decode_step(cfg: ModelConfig, params: Params, tokens: jax.Array,
         positions = cache.positions                          # absolute (RoPE)
     shared = _shared_xs(cfg, store)
 
-    def scan_body(x, xs):
-        lp, kc, vc, sh = xs if shared is not None else (*xs, None)
-        x, kc, vc, st = _layer_decode(cfg, x, lp, positions, kc, vc,
-                                      cache.length, sh, kernel=kernel)
-        return x, (kc, vc, st)
+    # the cache is loop state, not a scanned input: each layer writes its
+    # rows into the stack and attention reads the layer in place, so no
+    # layer slab is sliced out, relaid or stacked back
+    def scan_body(carry, xs):
+        x, k, v = carry
+        lp, i, sh = xs if shared is not None else (*xs, None)
+        x, k, v, st = _layer_decode(cfg, x, lp, positions, k, v, i,
+                                    cache.length, sh, kernel=kernel)
+        return (x, k, v), st
 
-    xs = ((params["layers"], cache.k, cache.v) if shared is None else
-          (params["layers"], cache.k, cache.v, shared))
-    x, (k_new, v_new, stats) = jax.lax.scan(scan_body, x, xs)
+    layer_ids = jnp.arange(cache.k.shape[0], dtype=jnp.int32)
+    xs = ((params["layers"], layer_ids) if shared is None else
+          (params["layers"], layer_ids, shared))
+    (x, k_new, v_new), stats = jax.lax.scan(scan_body,
+                                            (x, cache.k, cache.v), xs)
     x = L.rms_norm(x, params["final_norm"]["scale"], cfg.rms_eps)
     logits = _lm_head(cfg, params, x)
     out = (logits, KVCache(k_new, v_new, cache.length + 1, cache.offset))
@@ -517,7 +533,7 @@ def _layer_prefill_chunk(cfg: ModelConfig, x: jax.Array, lp: Params,
     """One chunk of a long prompt against the growing context view.
 
     x: (B, C, d) chunk activations (right-padded; ``chunk_len`` real);
-    kc/vc: (B, V, KH, D) scratch context holding ``base`` earlier tokens;
+    kc/vc: (B, V, KH·D) scratch context holding ``base`` earlier tokens;
     the chunk's fresh keys are written at ``base`` and causal attention
     runs over the whole view with ``kv_len = base + chunk_len`` masking.
     Returns (x_out, kc, vc, dispatch stats or None).
@@ -528,11 +544,14 @@ def _layer_prefill_chunk(cfg: ModelConfig, x: jax.Array, lp: Params,
     q = L.apply_rope(q, positions, cfg.rope_theta)
     k = L.apply_rope(k, positions, cfg.rope_theta)
     q = lsc(q, "batch", "seq", "heads", None)
-    kc = jax.lax.dynamic_update_slice_in_dim(kc, k.astype(kc.dtype), base,
-                                             axis=1)
-    vc = jax.lax.dynamic_update_slice_in_dim(vc, v.astype(vc.dtype), base,
-                                             axis=1)
+    B, C = k.shape[:2]
+    kc = jax.lax.dynamic_update_slice_in_dim(
+        kc, k.reshape(B, C, -1).astype(kc.dtype), base, axis=1)
+    vc = jax.lax.dynamic_update_slice_in_dim(
+        vc, v.reshape(B, C, -1).astype(vc.dtype), base, axis=1)
     kv_valid = base + chunk_len
+    k_ctx = kc.reshape(kc.shape[:2] + k.shape[2:])           # (B, V, KH, D)
+    v_ctx = vc.reshape(vc.shape[:2] + v.shape[2:])
 
     stats = None
     if shared is not None and cfg.moska.enabled:
@@ -549,7 +568,7 @@ def _layer_prefill_chunk(cfg: ModelConfig, x: jax.Array, lp: Params,
             routing = router_lib.route(pooled, semb, cfg.moska.top_k_chunks)
         with jax.named_scope("unique_attn"):
             o_u, lse_u = L.flash_attention(
-                q, kc, vc, causal=True, q_offset=start_pos + base,
+                q, k_ctx, v_ctx, causal=True, q_offset=start_pos + base,
                 kv_offset=start_pos, kv_len=kv_valid, window=cfg.attn_window,
                 return_lse=True)
         with jax.named_scope("shared_dispatch_gemm"):
@@ -564,7 +583,7 @@ def _layer_prefill_chunk(cfg: ModelConfig, x: jax.Array, lp: Params,
         stats = part.stats
     else:
         with jax.named_scope("unique_attn"):
-            o = L.flash_attention(q, kc, vc, causal=True,
+            o = L.flash_attention(q, k_ctx, v_ctx, causal=True,
                                   q_offset=start_pos + base,
                                   kv_offset=start_pos, kv_len=kv_valid,
                                   window=cfg.attn_window)
@@ -587,7 +606,7 @@ def prefill_chunk(cfg: ModelConfig, params: Params, tokens: jax.Array,
 
     tokens: (B, C) the chunk, right-padded; ``chunk_len`` (traced scalar)
     is the number of real tokens in it. ``cache`` is the scratch context
-    (L, B, V, KH, D) already holding ``cache.length`` earlier tokens.
+    (L, B, V, KH·D) already holding ``cache.length`` earlier tokens.
     Returns (logits at the chunk's last real token, cache extended by
     ``chunk_len``), plus the per-layer ``DispatchStats`` with
     ``return_stats``. One compiled program per (C, V) shape pair regardless

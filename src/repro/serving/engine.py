@@ -353,7 +353,8 @@ class ServingEngine:
             _, cache = self.model.prefill(self.params,
                                           jnp.asarray(toks)[None], cache)
             return build_store(jax.block_until_ready(cache.k)[:, 0],
-                               cache.v[:, 0], C)
+                               cache.v[:, 0], C,
+                               head_dim=self.cfg.head_dim)
 
     def _on_store_evicted(self, corpus_id: str) -> None:
         """Scheduler LRU eviction callback: drop the store's device arrays
@@ -458,7 +459,9 @@ class ServingEngine:
         """Scatter a (possibly bucket-padded) 1-batch prefill cache into
         the pool pages ``block_ids``; pads/slices the prefix to exactly
         tile the blocks (positions >= true_len are zeroed either way)."""
-        k, v = slot_k[:, 0], slot_v[:, 0]        # (L, S, KH, D)
+        heads = pool.k.shape[-2:]                # the pages' (KH, D)
+        k = slot_k[:, 0].reshape(slot_k.shape[0], -1, *heads)  # (L,S,KH,D)
+        v = slot_v[:, 0].reshape(slot_v.shape[0], -1, *heads)
         V = block_ids.shape[0] * pool.block_size
         S = k.shape[1]
         if S > V:

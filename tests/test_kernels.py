@@ -11,6 +11,7 @@ from repro.kernels.lse_merge import lse_merge
 from repro.kernels.paged_decode_attn import paged_decode_attention
 from repro.kernels.router_score import router_scores
 from repro.kernels.shared_chunk_attn import shared_chunk_attention
+from repro.models import layers as Lyr
 
 KEY = jax.random.PRNGKey(0)
 
@@ -50,22 +51,31 @@ def test_shared_chunk_attention(dtype, E, cap, H, KH, D, C, blk):
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("B,H,KH,D,S,blk", [
-    (4, 8, 2, 32, 100, 32),
-    (2, 4, 4, 64, 256, 256),
-    (3, 2, 1, 16, 33, 16),
-    (1, 16, 8, 128, 512, 128),
+@pytest.mark.parametrize("L,B,H,KH,D,S,blk,window", [
+    (2, 4, 8, 8, 32, 100, 32, 0),      # MHA, S ragged vs blk
+    (3, 3, 8, 2, 32, 64, 16, 0),       # GQA G=4, KH·D = 64 < 128
+    (2, 2, 16, 2, 64, 256, 128, 0),    # GQA G=8
+    (1, 3, 2, 1, 16, 33, 16, 0),       # one kv head of 16 lanes
+    (2, 4, 16, 4, 128, 512, 128, 48),  # sliding window
+    (2, 2, 16, 16, 64, 768, 256, 0),   # qwen1.5-0.5b's heads and max_seq
 ])
-def test_decode_attention(dtype, B, H, KH, D, S, blk):
+def test_decode_attention(dtype, L, B, H, KH, D, S, blk, window):
+    """The kernel reads layer L-1 of a stacked lane-dense (L, B, S, KH·D)
+    cache in place; it matches ``layers.decode_attention`` on that layer
+    split into heads, for lengths from 1 to S."""
     q = _rand(jax.random.fold_in(KEY, 1), (B, H, D), dtype)
-    k = _rand(jax.random.fold_in(KEY, 2), (B, S, KH, D), dtype)
-    v = _rand(jax.random.fold_in(KEY, 3), (B, S, KH, D), dtype)
+    k = _rand(jax.random.fold_in(KEY, 2), (L, B, S, KH * D), dtype)
+    v = _rand(jax.random.fold_in(KEY, 3), (L, B, S, KH * D), dtype)
     lens = jax.random.randint(jax.random.fold_in(KEY, 4), (B,), 1, S + 1)
-    o1, l1 = decode_attention(q, k, v, lens, block_s=blk)
-    o2, l2 = kref.decode_attention_ref(q, k, v, lens)
+    lens = lens.at[0].set(1).at[-1].set(S)
+    o1, l1 = decode_attention(q, k, v, lens, L - 1, window=window,
+                              block_s=blk)
+    o2, l2 = Lyr.decode_attention(q, k[L - 1].reshape(B, S, KH, D),
+                                  v[L - 1].reshape(B, S, KH, D), lens,
+                                  window=window, return_lse=True)
     np.testing.assert_allclose(np.float32(o1), np.float32(o2),
                                **_tols(dtype))
-    np.testing.assert_allclose(l1, l2, rtol=2e-2, atol=2e-2)
+    np.testing.assert_allclose(l1, l2, rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -134,13 +144,13 @@ def test_merge_of_decode_splits_equals_joint():
     decode over the whole cache (the disaggregated combine is exact)."""
     B, H, KH, D, S = 3, 8, 2, 32, 128
     q = _rand(jax.random.fold_in(KEY, 1), (B, H, D), jnp.float32)
-    k = _rand(jax.random.fold_in(KEY, 2), (B, S, KH, D), jnp.float32)
-    v = _rand(jax.random.fold_in(KEY, 3), (B, S, KH, D), jnp.float32)
+    k = _rand(jax.random.fold_in(KEY, 2), (1, B, S, KH * D), jnp.float32)
+    v = _rand(jax.random.fold_in(KEY, 3), (1, B, S, KH * D), jnp.float32)
     full = jnp.full((B,), S, jnp.int32)
-    oj, _ = decode_attention(q, k, v, full)
+    oj, _ = decode_attention(q, k, v, full, 0)
     half = jnp.full((B,), S // 2, jnp.int32)
-    o1, l1 = decode_attention(q, k[:, :S // 2], v[:, :S // 2], half)
-    o2, l2 = decode_attention(q, k[:, S // 2:], v[:, S // 2:], half)
+    o1, l1 = decode_attention(q, k[:, :, :S // 2], v[:, :, :S // 2], half, 0)
+    o2, l2 = decode_attention(q, k[:, :, S // 2:], v[:, :, S // 2:], half, 0)
     om, _ = lse_merge(jnp.stack([o1, o2]), jnp.stack([l1, l2]))
     np.testing.assert_allclose(np.float32(om), np.float32(oj),
                                rtol=2e-5, atol=2e-5)
@@ -162,9 +172,10 @@ def test_int8_store_end_to_end():
     ccache = init_kv_cache(cfg.num_layers, 1, CL, cfg.num_kv_heads,
                            cfg.head_dim, jnp.float32)
     _, ccache = dense.prefill(cfg, params, ctoks, ccache)
-    s_fp = build_store(ccache.k[:, 0], ccache.v[:, 0], cfg.moska.chunk_size)
+    s_fp = build_store(ccache.k[:, 0], ccache.v[:, 0], cfg.moska.chunk_size,
+                       head_dim=cfg.head_dim)
     s_q8 = build_store(ccache.k[:, 0], ccache.v[:, 0], cfg.moska.chunk_size,
-                       quantize=True)
+                       quantize=True, head_dim=cfg.head_dim)
     assert s_q8.quantized and s_q8.k.dtype == jnp.int8
     toks = jax.random.randint(jax.random.fold_in(KEY, 6), (B, 8), 0,
                               cfg.vocab_size)

@@ -162,7 +162,9 @@ def _check_merge_exactness(G, K, seed):
     lens = jax.random.randint(jax.random.fold_in(key, 6), (G,), 1, S + 1)
     r = route(q, store.emb[0], E)   # full routing => exact
     ctx = MoskaLayerContext(store.k[0], store.v[0], r)
-    out, _ = moska_decode_attention(q, kc, vc, lens, ctx,
+    # the unique path reads a stacked lane-dense cache (L, B, S, KH·D)
+    out, _ = moska_decode_attention(q, kc.reshape(1, G, S, KH * D),
+                                    vc.reshape(1, G, S, KH * D), lens, ctx,
                                     MoSKAConfig(top_k_chunks=E))
     for g in range(G):
         keys = jnp.concatenate([_tokens_major(store.k[0]),
@@ -194,6 +196,32 @@ if HAVE_HYPOTHESIS:
 # end-to-end: model + store
 # ---------------------------------------------------------------------------
 
+def test_build_store_from_lane_dense_prefill():
+    """The store built from a prefill's lane-dense (L, N, KH·D) cache
+    equals the one built from the same KV split into (L, N, KH, D), with
+    or without int8; without ``head_dim`` the slab is refused."""
+    cfg = dataclasses.replace(get_config("tinyllama-1.1b").reduced(),
+                              dtype="float32")
+    params = dense.init_params(cfg, jax.random.PRNGKey(0))
+    N, KH, D = 128, cfg.num_kv_heads, cfg.head_dim
+    toks = jax.random.randint(jax.random.fold_in(KEY, 9), (1, N), 0,
+                              cfg.vocab_size)
+    cache = init_kv_cache(cfg.num_layers, 1, N, KH, D, jnp.float32)
+    _, cache = dense.prefill(cfg, params, toks, cache)
+    k, v = cache.k[:, 0], cache.v[:, 0]
+    assert k.shape == (cfg.num_layers, N, KH * D)
+    C = cfg.moska.chunk_size
+    for quantize in (False, True):
+        got = build_store(k, v, C, quantize=quantize, head_dim=D)
+        want = build_store(k.reshape(-1, N, KH, D), v.reshape(-1, N, KH, D),
+                           C, quantize=quantize)
+        for a, b in zip(got, want):
+            if a is not None:
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    with pytest.raises(ValueError, match="head_dim"):
+        build_store(k, v, C)
+
+
 def test_moska_decode_equals_monolithic_context():
     cfg = dataclasses.replace(get_config("tinyllama-1.1b").reduced(),
                               dtype="float32")
@@ -207,7 +235,7 @@ def test_moska_decode_equals_monolithic_context():
                            cfg.head_dim, jnp.float32)
     _, ccache = dense.prefill(cfg, params, ctoks, ccache)
     store = build_store(ccache.k[:, 0], ccache.v[:, 0],
-                        cfg.moska.chunk_size)
+                        cfg.moska.chunk_size, head_dim=cfg.head_dim)
     cfgf = dataclasses.replace(cfg, moska=dataclasses.replace(
         cfg.moska, top_k_chunks=store.num_chunks))
     cache = init_kv_cache(cfg.num_layers, B, S + 4, cfg.num_kv_heads,
@@ -237,7 +265,7 @@ def test_sparse_routing_approximates_dense():
                            cfg.head_dim, jnp.float32)
     _, ccache = dense.prefill(cfg, params, ctoks, ccache)
     store = build_store(ccache.k[:, 0], ccache.v[:, 0],
-                        cfg.moska.chunk_size)
+                        cfg.moska.chunk_size, head_dim=cfg.head_dim)
     sparse = dataclasses.replace(cfg, moska=dataclasses.replace(
         cfg.moska, top_k_chunks=1))
     cache = init_kv_cache(cfg.num_layers, B, S + 4, cfg.num_kv_heads,
@@ -261,7 +289,7 @@ def test_pallas_kernel_path_matches_jnp_path():
                            cfg.head_dim, jnp.float32)
     _, ccache = dense.prefill(cfg, params, ctoks, ccache)
     store = build_store(ccache.k[:, 0], ccache.v[:, 0],
-                        cfg.moska.chunk_size)
+                        cfg.moska.chunk_size, head_dim=cfg.head_dim)
     cache = init_kv_cache(cfg.num_layers, B, 8, cfg.num_kv_heads,
                           cfg.head_dim, jnp.float32)
     toks = jax.random.randint(jax.random.fold_in(KEY, 6), (B, 4), 0,
@@ -287,7 +315,7 @@ def test_pallas_kernel_prefill_matches_jnp_path():
                            cfg.head_dim, jnp.float32)
     _, ccache = dense.prefill(cfg, params, ctoks, ccache)
     store = build_store(ccache.k[:, 0], ccache.v[:, 0],
-                        cfg.moska.chunk_size)
+                        cfg.moska.chunk_size, head_dim=cfg.head_dim)
     toks = jax.random.randint(jax.random.fold_in(KEY, 8), (1, S), 0,
                               cfg.vocab_size)
     outs = {}

@@ -106,8 +106,8 @@ def test_write_slot_prefix_no_stale_leak(dtype):
                     jnp.full((B,), S, jnp.int32), jnp.zeros((B,), jnp.int32))
     # new request: true length 3 padded into an 8-token bucket, store offset
     Sb, true_len, offset = 8, 3, 128
-    k_new = jax.random.normal(KEY, (L, 1, Sb, KH, D), dtype)
-    v_new = jax.random.normal(jax.random.fold_in(KEY, 1), (L, 1, Sb, KH, D),
+    k_new = jax.random.normal(KEY, (L, 1, Sb, KH * D), dtype)
+    v_new = jax.random.normal(jax.random.fold_in(KEY, 1), (L, 1, Sb, KH * D),
                               dtype)
     slot_cache = KVCache(k_new, v_new, jnp.full((1,), true_len, jnp.int32),
                          jnp.full((1,), offset, jnp.int32))
@@ -152,8 +152,8 @@ def test_write_slot_prefix_matches_merge_reference():
     cache = init_kv_cache(L, B, S, KH, D, jnp.float32)
     Sb = 5
     slot_cache = KVCache(
-        jax.random.normal(KEY, (L, 1, Sb, KH, D)),
-        jax.random.normal(jax.random.fold_in(KEY, 2), (L, 1, Sb, KH, D)),
+        jax.random.normal(KEY, (L, 1, Sb, KH * D)),
+        jax.random.normal(jax.random.fold_in(KEY, 2), (L, 1, Sb, KH * D)),
         jnp.full((1,), Sb, jnp.int32), jnp.full((1,), 64, jnp.int32))
     a = write_slot_prefix(cache, slot_cache, 2, Sb)
     b = _merge_slot_cache(cache, slot_cache, 2)
@@ -172,13 +172,42 @@ def test_write_slot_prefix_donatable():
     L, B, S, KH, D = 1, 2, 8, 1, 4
     cache = init_kv_cache(L, B, S, KH, D, jnp.float32)
     slot_cache = KVCache(
-        jnp.ones((L, 1, 4, KH, D)), 2 * jnp.ones((L, 1, 4, KH, D)),
+        jnp.ones((L, 1, 4, KH * D)), 2 * jnp.ones((L, 1, 4, KH * D)),
         jnp.full((1,), 4, jnp.int32), jnp.zeros((1,), jnp.int32))
     wr = jax.jit(write_slot_prefix, donate_argnums=(0,))
     out = wr(cache, slot_cache, jnp.int32(1), jnp.int32(4))
     assert np.asarray(out.k[:, 1, :4]).all()
     with pytest.raises(RuntimeError):
         _ = np.asarray(cache.k)   # donated: input buffer was consumed
+
+
+def test_engine_decode_matches_a_forward_without_cache():
+    """Prefill then decode through the slotted lane-dense cache, over
+    several waves that reuse both slots, serves at every step the token a
+    float32 forward over the whole sequence, with no cache, ranks first
+    (teacher-forced on the served tokens)."""
+    import dataclasses
+    from repro.models import dense
+    cfg = dataclasses.replace(get_config("qwen1.5-0.5b").reduced(),
+                              dtype="float32")
+    params = build_model(cfg).init(KEY)
+    eng = ServingEngine(cfg, params, EngineConfig(
+        max_slots=2, max_seq=48, cache_dtype=jnp.float32))
+    rng = np.random.default_rng(3)
+    for n, new in ((5, 6), (11, 9), (3, 4), (17, 7), (8, 5)):
+        eng.submit(rng.integers(0, cfg.vocab_size, n).tolist(), new)
+    done = eng.run()
+    assert len(done) == 5 and eng.metrics["decode_steps"] >= 9
+    for r in done:
+        seq = jnp.asarray(list(r.prompt) + list(r.generated), jnp.int32)
+        x = dense.embed_inputs(cfg, params, seq[None])
+        h, _ = dense.forward_hidden(cfg, params, x, jnp.arange(len(seq)),
+                                    remat=False)
+        logits = np.asarray(jnp.einsum("sd,vd->sv", h[0],
+                                       dense.unembed_matrix(cfg, params)))
+        ref = logits[len(r.prompt) - 1:len(seq) - 1]
+        gap = ref.max(-1) - ref[np.arange(len(ref)), r.generated]
+        assert len(r.generated) == len(ref) and gap.max() <= 1e-3, gap
 
 
 # ---------------------------------------------------------------------------
