@@ -6,13 +6,16 @@ without a chip, and print each one's ``memory_analysis()`` and compile time.
 
 Covers the weights' creation, the corpus registration (one prefill of the
 whole corpus), the decode step and the largest prefill bucket the mix's
-prompts reach, each with the engine's defaults (host-callback metrics on).
-Nothing runs, so nothing here is a time or a speed on the chip.
+prompts reach, each with the engine's defaults, for the system that the
+configuration's file names. Nothing runs, so nothing here is a time or a
+speed on the chip.
 """
 from __future__ import annotations
 
 import argparse
+import importlib
 import os
+import pkgutil
 import sys
 import time
 
@@ -31,12 +34,18 @@ def main(argv=None) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     from chipbench import run
-    from chipbench.systems import repro_dense as S
-    from repro import obs
     from repro.serving.engine import EngineConfig, ServingEngine
     jax.config.update("jax_enable_compilation_cache", False)
 
     cell = run.load_cell(args.workload)
+    S = run.prepare(cell, use_cache=False)
+    # the program's kernels pick interpret mode from the default backend,
+    # the CPU here; compile them with Mosaic for the described chip
+    import repro.kernels as K
+    for k in pkgutil.iter_modules(K.__path__):
+        mod = importlib.import_module(f"{K.__name__}.{k.name}")
+        if hasattr(mod, "resolve_interpret"):
+            mod.resolve_interpret = bool
     conf, mix = cell.conf, cell.mix
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
@@ -63,7 +72,6 @@ def main(argv=None) -> int:
     cfg = S.model_config(conf)
     report("weights", lambda: S.program_params(conf, 0))
     params = sds(jax.eval_shape(lambda: S.program_params(conf, 0)))
-    obs.enable_jit_metrics(True)
     eng = ServingEngine(cfg, params, EngineConfig(
         max_slots=mix["max_slots"], max_seq=mix["max_seq"]))
     model = eng.model
@@ -77,7 +85,8 @@ def main(argv=None) -> int:
             cache = model.init_cache(1, N, jnp.bfloat16)
             _, cache = model.prefill(p, t, cache)
             from repro.core.shared_kv import build_store
-            return build_store(cache.k[:, 0], cache.v[:, 0], C)
+            return build_store(cache.k[:, 0], cache.v[:, 0], C,
+                               head_dim=cfg.head_dim)
         report("corpus registration", register, params, toks)
         store = sds(jax.eval_shape(register, params, toks))
     use = store is not None

@@ -8,8 +8,10 @@ The cell is an entry of ``workloads`` in ``BENCHMARK.json``. Its
 configuration (``chipbench/configs/<config>.json``), its traffic mix
 (``chipbench/traffic/<traffic>.json``), its check limits
 (``chipbench/cells/<cell>.json``) and each metric's reader
-(``chipbench/metrics/<metric>.py``) are found by name, so a new cell, mix,
-configuration or metric is a new file and a new entry.
+(``chipbench/metrics/<metric>.py``) are found by name, and so are the
+system, the reference and the work module that the configuration's file
+names, so a new cell, mix, configuration or metric is a new file and a new
+entry.
 
 Set-up builds the weights on the device from the seed, registers the
 corpus, compiles every prefill program the mix's prompt lengths can reach
@@ -70,6 +72,12 @@ class WindowClosed(Exception):
 # the cell's files
 # ---------------------------------------------------------------------------
 
+# what a configuration's file names: the system under test
+# (``chipbench/systems/<system>.py``), the plain reference and the module
+# that counts its work (``chipbench/configs/<reference>.py``, ``<work>.py``)
+CONF_MODULES = ("system", "reference", "work")
+
+
 @dataclass
 class Cell:
     entry: dict
@@ -78,6 +86,7 @@ class Cell:
     limits: dict
     bench: dict
     root: str
+    work: object        # the configuration's work module
 
     def metrics(self, kind: str) -> List[dict]:
         """The cell's end-to-end (``kind='end_to_end'``) or per-layer
@@ -98,13 +107,20 @@ def load_cell(workload: str, bench_path: Optional[str] = None) -> Cell:
                          f"known: {sorted(entries)}")
     entry = entries[workload]
     confs = {c["name"]: c for c in bench["configs"]}
-    with open(os.path.join(root, confs[entry["config"]]["file"])) as f:
+    conf_path = os.path.join(root, confs[entry["config"]]["file"])
+    with open(conf_path) as f:
         conf = json.load(f)
+    missing = [k for k in CONF_MODULES if not conf.get(k)]
+    if missing:
+        raise ValueError(f"{conf_path} names no module for "
+                         f"{', '.join(missing)}; a configuration's file "
+                         f"names its {', '.join(CONF_MODULES)}")
     mix = loadgen.load(entry["traffic"], os.path.join(root, "chipbench"))
     with open(os.path.join(root, "chipbench", "cells",
                            f"{workload}.json")) as f:
         limits = json.load(f)
-    return Cell(entry, conf, mix, limits, bench, root)
+    return Cell(entry, conf, mix, limits, bench, root,
+                _module(root, "configs", conf["work"]))
 
 
 def _module(root: str, sub: str, name: str):
@@ -270,6 +286,7 @@ class Feeder:
 class RunData:
     conf: dict
     mix: dict
+    work: object                   # the configuration's work module
     peaks: dict
     seconds: float                 # length of the window read
     t0: float
@@ -303,8 +320,9 @@ def run_data(cell: Cell, drv: Feeder, t0: float, t1: float, c0, c1,
              setup_s: float, peaks: dict, trace=None) -> RunData:
     recs = [r for r in drv.recs if t0 <= r.due < t1]
     waves = [w for w in drv.waves if t0 <= w.end <= t1]
-    return RunData(cell.conf, cell.mix, peaks, t1 - t0, t0, t1, recs,
-                   drv.recs, waves, counter_delta(c0, c1), setup_s, trace)
+    return RunData(cell.conf, cell.mix, cell.work, peaks, t1 - t0, t0, t1,
+                   recs, drv.recs, waves, counter_delta(c0, c1), setup_s,
+                   trace)
 
 
 def read_metrics(cell: Cell, kind: str, data: RunData) -> Dict[str, dict]:

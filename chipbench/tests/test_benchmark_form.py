@@ -49,3 +49,17 @@ def test_every_metric_has_a_reader(kind):
             assert cell in CELLS, (m["name"], cell)
         if kind == "end_to_end":
             assert 0.01 <= m["bound"] <= 0.25, m["name"]
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in BENCH["configs"]])
+def test_configuration_names_its_modules(config):
+    """The system under test, the plain reference and the work module
+    that a configuration's file names are files of the benchmark."""
+    entry = {c["name"]: c for c in BENCH["configs"]}[config]
+    with open(os.path.join(helpers.REPO, entry["file"])) as f:
+        conf = json.load(f)
+    for key, sub in (("system", "systems"), ("reference", "configs"),
+                     ("work", "configs")):
+        assert conf.get(key), (config, key)
+        assert os.path.isfile(os.path.join(helpers.BENCH, sub,
+                                           f"{conf[key]}.py")), (config, key)
